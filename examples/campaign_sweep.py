@@ -96,6 +96,7 @@ from repro.core.detectors import (DEFAULT_DETECTORS,  # noqa: E402
 from repro.mitigate.policy import available_policies  # noqa: E402
 from repro.core.recorder import RECORDER_IMPLS  # noqa: E402
 from repro.core.sloth import SlothConfig  # noqa: E402
+from repro.compile_cache import enable_compile_cache  # noqa: E402
 
 
 def make_grid(args) -> CampaignGrid:
@@ -180,6 +181,7 @@ def main(argv=None) -> int:
                          "(batched), or 'both' to run the campaign twice "
                          "and assert identical verdicts + compression")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     detectors = (DEFAULT_DETECTORS if args.all_detectors
                  else tuple(args.detectors) if args.detectors

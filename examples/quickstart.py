@@ -7,10 +7,13 @@ import sys
 import os
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
+from repro.compile_cache import enable_compile_cache
 from repro.core.failures import FailSlow
 from repro.core.graph import build_workload
 from repro.core.routing import Mesh2D
 from repro.core.sloth import Sloth
+
+enable_compile_cache()
 
 # 1. a 4×4 many-core accelerator running ResNet-50 inference
 mesh = Mesh2D(4)

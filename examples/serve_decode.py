@@ -13,10 +13,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs.base import get_config
 from repro.models import transformer as T
 from repro.serving.engine import EngineConfig, Request, ServeEngine
 
+enable_compile_cache()
 cfg = get_config("smollm-135m", smoke=True)
 params = T.init_model(cfg, jax.random.PRNGKey(0), dtype=jnp.float32)
 engine = ServeEngine(cfg, params, EngineConfig(batch=4, cache_len=128))

@@ -11,10 +11,12 @@ import os
 import sys
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
+from repro.compile_cache import enable_compile_cache
 from repro.core.failures import FailSlow
 from repro.distributed.telemetry import (MitigationPolicy, PodDetector,
                                          PodSimulator, PodTelemetryConfig)
 
+enable_compile_cache()
 cfg = PodTelemetryConfig(mesh_w=16, mesh_h=16)
 detector = PodDetector(cfg)
 policy = MitigationPolicy(n_shards=16)

@@ -23,9 +23,10 @@ Three layers of accounting, each matching a measured quantity exactly
   ``kernels/sketch_update/ref.make_state`` (4 int32 ``[d,m]`` tables +
   11 ``[L]`` vectors (5 int32, 6 f32) + a scalar counter), the
   drained-eviction buffer of ``make_drain`` (10 ``[cap]`` arrays + a
-  scalar), and the Pallas kernel's full VMEM-resident set (those two
-  plus the 6 streamed trace arrays of one ``block``) — mirroring the
-  ``BlockSpec`` shapes in ``kernels/sketch_update/kernel.py``.
+  scalar), and the Pallas kernel's full on-chip set in SMEM and VMEM
+  (those two plus the 6 streamed trace arrays of one ``block``) —
+  mirroring the ``BlockSpec`` shapes in
+  ``kernels/sketch_update/kernel.py``.
 
 The budget check (:func:`validate_config` / :func:`validate_params`)
 gates the *persistent* per-chip footprint: for each side (comp + comm)
@@ -106,9 +107,10 @@ def drain_bytes(capacity: int) -> int:
 
 def pallas_vmem_bytes(p: SketchParams, *, block: int = 256,
                       drain_capacity: int = 256) -> int:
-    """VMEM-resident set of one ``sketch_insert`` call: the streamed
-    trace block (``trace_spec`` × 6 arrays), the aliased packed state
-    (pinned across the sequential grid), and the drain buffer.  Derived
+    """On-chip set of one ``sketch_insert`` call, SMEM and VMEM together:
+    the streamed trace block (``trace_spec`` × 6 arrays), the aliased
+    packed state (pinned across the sequential grid), and the drain
+    buffer.  Derived
     from the BlockSpec shapes in ``kernels/sketch_update/kernel.py``."""
     return (block * TRACE_RECORD_BYTES
             + packed_state_bytes(p)

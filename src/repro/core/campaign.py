@@ -174,7 +174,9 @@ Execution model
   defining module to be resolvable inside spawned workers.  A ``cache=``
   argument is not consulted on this path.  Outcomes are collected in
   scenario order and are **bit-identical** to serial/thread execution for
-  any worker count.
+  any worker count.  It runs on the CPU backend only: a TPU belongs to
+  one process, which the parent already holds, so on a TPU backend
+  ``run_campaign`` refuses to spawn the pool.
 
 ``workers=None`` → cpu count; ``0``/``1`` or a single-scenario grid →
 serial in-process execution for either executor.
@@ -1189,6 +1191,18 @@ class CampaignResult:
         return "\n".join(lines)
 
 
+def _refuse_process_pool_on_tpu() -> None:
+    """A chip belongs to one process: spawned workers that import the
+    analysis pipeline would each reach for the TPU this process holds,
+    and fail or hang."""
+    import jax
+    if jax.default_backend() == "tpu":
+        raise RuntimeError(
+            "executor='process' cannot run on a TPU backend: its workers "
+            "would each open the chip this process already holds; use "
+            "executor='thread' or workers=1 (serial)")
+
+
 #: Chunk count used when a campaign requests ``streaming=True``.
 DEFAULT_STREAM_CHUNKS = 4
 
@@ -1246,6 +1260,7 @@ def run_campaign(grid: CampaignGrid, *, workers: int | None = None,
     parallel = workers > 1 and len(scenarios) > 1
 
     if executor == "process" and parallel:
+        _refuse_process_pool_on_tpu()
         # spawn, not fork: the analysis pipeline jits through JAX, whose
         # thread pools make fork() after first use prone to deadlock.
         # Workers re-import the package cleanly (sys.path is inherited).

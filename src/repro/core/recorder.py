@@ -58,6 +58,10 @@ class RecorderOutput:
     n_comp_drained: int = 0
     n_comm_drained: int = 0
     impl: str = "ref"
+    # platform of the device that held the sketch state ("tpu", "cpu");
+    # "host" where the state never left numpy (the ref oracle, or a
+    # trace with no records)
+    device: str = "host"
 
     @property
     def raw_bytes(self) -> int:
@@ -121,7 +125,7 @@ def _sketch_runs_ref(params: SketchParams, keys, reps, durs, vals, t0s,
     """Per-run numpy oracle path."""
     sk = FailSlowSketch(params)
     sk.insert_runs(keys, reps, durs, vals, t0s, dts)
-    return sk.patterns(), sk.compressed_bytes(), sk.n_evicted
+    return sk.patterns(), sk.compressed_bytes(), sk.n_evicted, "host"
 
 
 def _sketch_runs_batched(params: SketchParams, keys, reps, durs, vals,
@@ -151,7 +155,12 @@ def _sketch_runs_batched(params: SketchParams, keys, reps, durs, vals,
     # plus one exact Stage-2 slot per drained pattern
     return (pats,
             params.total_bytes() + n_drained * params.stage2_slot_bytes(),
-            n_drained)
+            n_drained, state_platform(state))
+
+
+def state_platform(state: dict) -> str:
+    """Platform of the device holding a packed sketch state."""
+    return next(iter(state["freq"].devices())).platform
 
 
 def _sketch_runs(impl: str, params: SketchParams, keys, reps, durs, vals,
@@ -191,9 +200,10 @@ def record(sim: SimResult, params: SketchParams,
     comp_patterns: list[Pattern] = []
     comp_bytes = params.total_bytes()
     n_comp_drained = 0
+    device = "host"
     if len(comp["core"]):
         runs = comp_runs(comp, instr_per_task)
-        comp_patterns, comp_bytes, n_comp_drained = _sketch_runs(
+        comp_patterns, comp_bytes, n_comp_drained, device = _sketch_runs(
             impl, params, *runs, P.COMP_KEY_TAG)
         n_comp = len(runs[0]) * instr_per_task
 
@@ -204,7 +214,7 @@ def record(sim: SimResult, params: SketchParams,
     n_comm_drained = 0
     if len(comm["src"]):
         runs = comm_runs(comm, packet_bytes, max_packets, hop_latency)
-        comm_patterns, comm_bytes, n_comm_drained = _sketch_runs(
+        comm_patterns, comm_bytes, n_comm_drained, device = _sketch_runs(
             impl, comm_params, *runs, P.COMM_KEY_TAG)
         n_comm = int(runs[1].sum())
 
@@ -220,4 +230,5 @@ def record(sim: SimResult, params: SketchParams,
         n_comp_drained=n_comp_drained,
         n_comm_drained=n_comm_drained,
         impl=impl,
+        device=device,
     )

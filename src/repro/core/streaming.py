@@ -44,7 +44,7 @@ import numpy as np
 
 from . import probes as P
 from .recorder import (RECORDER_IMPLS, RecorderOutput, comm_runs,
-                       comp_runs)
+                       comp_runs, state_platform)
 from .simulator import SimResult
 from .sketch import (FailSlowSketch, Pattern, SketchParams,
                      accumulate_pattern, split_key)
@@ -162,6 +162,11 @@ class _SketchStream:
             accumulate_pattern(merged, pat)
         return sorted(merged.values(), key=lambda p: p.arrival)
 
+    def device(self) -> str:
+        if self.impl == "ref" or self.state is None:
+            return "host"
+        return state_platform(self.state)
+
     def drained_count(self) -> int:
         return self.sk.n_evicted if self.impl == "ref" else self.n_drained
 
@@ -248,6 +253,9 @@ class StreamingRecorder:
             n_comp_drained=self._comp.drained_count(),
             n_comm_drained=self._comm.drained_count(),
             impl=self.impl,
+            device=next((d for d in (self._comp.device(),
+                                     self._comm.device()) if d != "host"),
+                        "host"),
         )
 
 

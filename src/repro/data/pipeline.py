@@ -6,6 +6,14 @@ we use a splitmix64-style integer hash in numpy): batch ``i`` is a pure
 function of (seed, i), so any host can materialise any step independently —
 which is what makes restart/elastic-reshard trivial: the checkpoint stores
 only ``step``.
+
+Token ids follow a Zipf-like law, as tokenized text does: each draw picks
+an octave ``k`` uniformly below the vocabulary's bit length, then an id
+uniformly in ``[2^k − 1, 2^(k+1) − 1)`` (ids past the vocabulary fold
+back), so id ``t`` has probability about ``1 / (t · bits)``.  A uniform
+stream would hold nothing a model can learn — its loss cannot fall below
+``ln vocab`` — while this one has a unigram law to fit, in exact integer
+arithmetic on every host.
 """
 
 from __future__ import annotations
@@ -50,7 +58,11 @@ class TokenPipeline:
         base = (np.uint64(c.seed) << np.uint64(32)) + np.uint64(step)
         idx = np.arange(c.batch * c.seq, dtype=np.uint64) \
             + base * np.uint64(c.batch * c.seq)
-        toks = _splitmix64(idx) % np.uint64(c.vocab)
+        h = _splitmix64(idx)
+        octave = (h >> np.uint64(32)) % np.uint64(int(c.vocab).bit_length())
+        width = np.uint64(1) << octave
+        toks = (width - np.uint64(1) + (h & np.uint64(0xFFFFFFFF)) % width) \
+            % np.uint64(c.vocab)
         return toks.astype(np.int32).reshape(c.batch, c.seq)
 
     def shard_at(self, step: int) -> np.ndarray:

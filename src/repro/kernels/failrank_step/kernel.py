@@ -19,6 +19,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .. import resolve_interpret
+
 # Structural contract checked by repro.analysis.kernel_audit: rank-1
 # grid over column stripes; each stripe writes disjoint output blocks
 # and no state is aliased across steps.
@@ -41,7 +43,7 @@ def _kernel(w_ref, l_ref, s_ref, s0_ref, s_out_ref, l_out_ref, *,
                                              "interpret"))
 def failrank_step(w, l, s, s0, *, lam=0.55, alpha=0.1, beta=0.3,
                   gamma=0.6, col_block: int = 128,
-                  interpret: bool = True):
+                  interpret: bool | None = None):
     """w/l [n,n] (w[u,v] = propagation weight), s/s0 [n] → (s', L')."""
     n = w.shape[0]
     nb = -(-n // col_block)
@@ -71,6 +73,6 @@ def failrank_step(w, l, s, s0, *, lam=0.55, alpha=0.1, beta=0.3,
             jax.ShapeDtypeStruct((1, npad), jnp.float32),
             jax.ShapeDtypeStruct((npad, npad), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(w, l, s.reshape(npad, 1), s0.reshape(1, npad))
     return s_new[0, :n], l_new[:n, :n]

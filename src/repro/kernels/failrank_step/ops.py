@@ -24,7 +24,7 @@ def mcg_dense(mcg: MCG):
 
 
 def failrank_dense(mcg: MCG, params: FailRankParams = FailRankParams(),
-                   impl: str = "pallas", interpret: bool = True):
+                   impl: str = "pallas", interpret: bool | None = None):
     """Returns (node_scores softmaxed, raw s, raw dense L, iterations)."""
     import jax.numpy as jnp
     w, l = mcg_dense(mcg)

@@ -22,6 +22,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .. import resolve_interpret
+
 NEG_INF = -1e30
 
 # Structural contract checked by repro.analysis.kernel_audit: rank-3
@@ -87,7 +89,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 @functools.partial(jax.jit, static_argnames=("causal", "window", "q_block",
                                              "kv_block", "interpret"))
 def flash_attention(q, k, v, *, causal=True, window=None, q_block=128,
-                    kv_block=128, interpret=True):
+                    kv_block=128, interpret=None):
     """q [BH, Sq, D], k/v [BH, Skv, D] → [BH, Sq, D]."""
     bh, sq, d = q.shape
     skv = k.shape[1]
@@ -118,6 +120,6 @@ def flash_attention(q, k, v, *, causal=True, window=None, q_block=128,
             pltpu.VMEM((q_block, 1), jnp.float32),
             pltpu.VMEM((q_block, d), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(q, k, v)
     return out[:, :sq]

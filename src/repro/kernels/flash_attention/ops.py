@@ -14,7 +14,7 @@ from .ref import attention_ref
 
 
 def gqa_attention(q, k, v, *, causal=True, window=None, impl="pallas",
-                  interpret=True, q_block=128, kv_block=128):
+                  interpret=None, q_block=128, kv_block=128):
     b, s, hq, d = q.shape
     t, hk = k.shape[1], k.shape[2]
     g = hq // hk

@@ -1,9 +1,9 @@
 """Jitted public API for the sketch-update kernel.
 
 ``insert(state, traces, impl=...)`` dispatches between the Pallas kernel
-(TPU target; ``interpret=True`` on CPU), the vectorized multi-record batch
-path (``impl="batched"``, the campaign hot path) and the pure-jnp scan
-oracle.  ``insert_runs`` is the run-compressed entry point (the
+(compiled on a TPU, interpreted on the CPU; see ``repro.kernels``), the
+vectorized multi-record batch path (``impl="batched"``, the campaign hot
+path) and the pure-jnp scan oracle.  ``insert_runs`` is the run-compressed entry point (the
 vectorized analogue of ``FailSlowSketch.insert_run``; the recorder's
 on-device path).  ``patterns(state)`` decodes Stage-2 into the same
 Pattern records the numpy reference produces; given a drained-eviction
@@ -32,7 +32,8 @@ def make_drain(capacity: int):
 
 
 def insert(state, lo, hi, dur, val, t, *, params: SketchParams,
-           impl: str = "pallas", interpret: bool = True, block: int = 256,
+           impl: str = "pallas", interpret: bool | None = None,
+           block: int = 256,
            drain=None):
     """Per-record batched insertion.  With ``drain`` (a ``make_drain``
     buffer), FIFO-evicted Stage-2 rows are preserved and ``(state,

@@ -9,7 +9,7 @@ from .ref import ssd_ref
 
 
 def ssd(x, dt, a, b, c, *, impl: str = "pallas", chunk: int = 128,
-        interpret: bool = True):
+        interpret: bool | None = None):
     """Model layout: x [B,S,H,P], dt [B,S,H], a [H], b/c [B,S,G,N] →
     (y [B,S,H,P], state [B,H,P,N])."""
     bsz, s, h, p = x.shape

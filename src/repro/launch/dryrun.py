@@ -1,6 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run: lower + compile every (arch × shape × mesh) cell.
 
 For each cell this prints/records:
@@ -16,10 +13,16 @@ Usage:
   python -m repro.launch.dryrun --all          # orchestrates subprocesses
 Results accumulate in dryrun_results.json (one entry per cell) so an
 interrupted sweep resumes where it stopped.
+
+This is a CPU-only compile tool: ``main()`` pins JAX to the CPU with 512
+forced host devices before anything imports JAX, and the per-cell
+subprocesses inherit that environment, so no process of a sweep reaches
+for an attached chip.
 """
 
 import argparse
 import json
+import os
 import re
 import subprocess
 import sys
@@ -188,6 +191,10 @@ def orchestrate(archs, shapes, meshes, force=False, variant="",
 
 
 def main():
+    # CPU with 512 virtual devices, for this process and the cell
+    # subprocesses it spawns (they inherit os.environ)
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch")
     ap.add_argument("--shape")

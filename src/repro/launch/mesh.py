@@ -6,7 +6,8 @@ axis is an outer data-parallel axis (gradient all-reduce crosses the
 inter-pod links; decode shards batch across pods).
 
 Defined as functions so importing this module never touches jax device
-state; ``dryrun.py`` sets XLA_FLAGS for 512 host devices before any import.
+state; ``dryrun.main()`` pins the CPU with 512 host devices before any
+JAX import.
 """
 
 from __future__ import annotations

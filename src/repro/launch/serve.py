@@ -25,6 +25,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..compile_cache import enable_compile_cache
 from ..configs.base import get_config
 from ..distributed.telemetry import PodTelemetryConfig, StepTelemetry
 from ..models import transformer as T
@@ -47,6 +48,7 @@ def main(argv=None):
     ap.add_argument("--telemetry-window", type=int, default=8,
                     help="decode steps per streaming-detector window")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config(args.arch, smoke=args.smoke)
     params = T.init_model(cfg, jax.random.PRNGKey(args.seed),

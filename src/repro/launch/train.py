@@ -36,6 +36,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..checkpoint import store
+from ..compile_cache import enable_compile_cache
 from ..configs.base import get_config
 from ..data.pipeline import DataConfig, TokenPipeline
 from ..distributed.telemetry import PodTelemetryConfig, StepTelemetry
@@ -74,6 +75,7 @@ def main(argv=None):
                     "window (CI smoke assertion)")
     ap.add_argument("--log-every", type=int, default=10)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config(args.arch, smoke=args.smoke)
     opt_cfg = adamw.AdamWConfig(lr=args.lr, warmup_steps=10,

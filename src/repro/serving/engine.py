@@ -12,7 +12,9 @@ percentile computed downstream.  An optional ``step_hook(kind, dt)``
 callback fires after each step (``kind`` is ``'prefill'`` or
 ``'decode'``) — the live telemetry tap ``launch/serve.py --telemetry``
 uses to stream decode timings into the pod detector
-(:class:`~repro.distributed.telemetry.StepTelemetry`).
+(:class:`~repro.distributed.telemetry.StepTelemetry`).  Each timing ends
+with ``block_until_ready`` on the step's next tokens, so it covers the
+device step and not only its dispatch.
 """
 
 from __future__ import annotations
@@ -42,6 +44,12 @@ class EngineConfig:
     dtype: object = jnp.float32
 
 
+def _greedy(out):
+    """``(logits, *rest)`` → ``(next tokens [B, 1] int32, *rest)``."""
+    nxt = jnp.argmax(out[0][:, -1], axis=-1)[:, None].astype(jnp.int32)
+    return (nxt,) + tuple(out[1:])
+
+
 class ServeEngine:
     def __init__(self, cfg, params, ecfg: EngineConfig, step_hook=None):
         self.cfg = cfg
@@ -53,15 +61,17 @@ class ServeEngine:
         self.decode_times: list[float] = []
         self.step_hook = step_hook             # fn(kind, dt) | None
 
+        # both steps return the greedy next tokens [B, 1] in place of
+        # the logits: (next, cache[, memory])
         self._prefill = jax.jit(
-            lambda p, toks, frames=None: T.prefill(
+            lambda p, toks, frames=None: _greedy(T.prefill(
                 cfg, p, toks,
                 T.init_cache(cfg, ecfg.batch, ecfg.cache_len,
                              dtype=ecfg.dtype),
-                enc_frames=frames, remat=False))
+                enc_frames=frames, remat=False)))
         self._decode = jax.jit(
-            lambda p, toks, cache, pos, memory=None: T.decode_step(
-                cfg, p, toks, cache, pos, memory=memory))
+            lambda p, toks, cache, pos, memory=None: _greedy(T.decode_step(
+                cfg, p, toks, cache, pos, memory=memory)))
 
     def submit(self, req: Request):
         self.queue.append(req)
@@ -93,9 +103,9 @@ class ServeEngine:
             args = (self.params, toks) + ((enc_frames,) if self.cfg.enc_dec
                                           else ())
             out = self._prefill(*args)
-            last, cache = out[0], out[1]
+            nxt, cache = out[0], out[1]
             memory = out[2] if self.cfg.enc_dec else None
-            nxt = jnp.argmax(last[:, -1], axis=-1)[:, None].astype(jnp.int32)
+            nxt.block_until_ready()     # time the device step, not the enqueue
             self._record("prefill", time.perf_counter() - t0)  # lint: allow-wallclock
             max_new = max(r.max_new for r in batch)
             for k in range(max_new):
@@ -103,10 +113,9 @@ class ServeEngine:
                     if r.rid >= 0 and len(r.out_tokens) < r.max_new:
                         r.out_tokens.append(int(t))
                 t0 = time.perf_counter()  # lint: allow-wallclock
-                logits, cache = self._decode(self.params, nxt, cache,
-                                             jnp.int32(s + k), memory)
-                nxt = jnp.argmax(logits[:, -1], axis=-1)[:, None]\
-                    .astype(jnp.int32)
+                nxt, cache = self._decode(self.params, nxt, cache,
+                                          jnp.int32(s + k), memory)
+                nxt.block_until_ready()
                 self._record("decode", time.perf_counter() - t0)  # lint: allow-wallclock
             done.extend(r for r in batch if r.rid >= 0)
         return done

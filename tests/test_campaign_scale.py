@@ -49,6 +49,19 @@ def test_process_executor_serial_fallback(serial_result):
     assert res.outcomes == serial_result.outcomes
 
 
+def test_process_executor_refused_on_tpu(monkeypatch, serial_result):
+    """A TPU belongs to one process: on a TPU backend the process pool
+    is refused before any worker spawns, pointing at the executors that
+    stay in-process; serial runs under the same backend still go."""
+    import jax
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(RuntimeError, match="executor='thread'"):
+        run_campaign(TINY, workers=2, executor="process")
+    res = run_campaign(TINY, workers=1, executor="process",
+                       cache=DeploymentCache())
+    assert res.outcomes == serial_result.outcomes
+
+
 def test_unknown_executor_rejected():
     with pytest.raises(ValueError, match="unknown executor"):
         run_campaign(TINY, executor="gremlin")

@@ -1,5 +1,8 @@
-"""Pallas kernel validation: shape/dtype sweeps vs the pure-jnp oracles
-(interpret mode on CPU)."""
+"""Pallas kernel validation: shape/dtype sweeps vs the pure-jnp oracles.
+
+Every kernel call takes its ``interpret`` flag from the shared backend
+resolution (``repro.kernels.resolve_interpret``): the interpreter on the
+CPU, the compiled kernel on a TPU."""
 
 import jax
 import jax.numpy as jnp
@@ -10,13 +13,36 @@ from proptest import given, settings, st
 pytestmark = pytest.mark.filterwarnings("ignore::DeprecationWarning")
 
 
+@pytest.fixture
+def interpret():
+    from repro.kernels import resolve_interpret
+    return resolve_interpret()
+
+
+@pytest.mark.parametrize("backend,asked,expect", [
+    ("cpu", None, True), ("cpu", True, True), ("cpu", False, False),
+    ("tpu", None, False), ("tpu", False, False),
+    ("tpu", True, RuntimeError), ("gpu", None, RuntimeError),
+])
+def test_interpret_resolution(monkeypatch, backend, asked, expect):
+    """Interpret mode on the CPU, compiled on a TPU, never the emulator
+    on a TPU, and no silent choice on any other backend."""
+    from repro.kernels import resolve_interpret
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    if expect is RuntimeError:
+        with pytest.raises(RuntimeError):
+            resolve_interpret(asked)
+    else:
+        assert resolve_interpret(asked) is expect
+
+
 # ---------------------------------------------------------------------------
 # sketch_update
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("d,m,H,L,n", [(1, 32, 2, 8, 300), (2, 64, 4, 16,
                                        700), (4, 128, 8, 32, 500)])
-def test_sketch_kernel_matches_ref(d, m, H, L, n):
+def test_sketch_kernel_matches_ref(d, m, H, L, n, interpret):
     from repro.core.sketch import SketchParams, split_key
     from repro.kernels.sketch_update import ops as O, ref as R
     p = SketchParams(d=d, m=m, H=H, L=L)
@@ -29,7 +55,7 @@ def test_sketch_kernel_matches_ref(d, m, H, L, n):
     args = tuple(jnp.asarray(x) for x in (lo, hi, dur, val, t))
     st_r = R.insert_batch(R.make_state(p), *args, H=p.H)
     st_p = O.insert(O.make_state(p), *args, params=p, impl="pallas",
-                    block=128)
+                    block=128, interpret=interpret)
     for k in st_r:
         a, b = np.asarray(st_r[k]), np.asarray(st_p[k])
         if a.dtype.kind == "i":
@@ -39,7 +65,7 @@ def test_sketch_kernel_matches_ref(d, m, H, L, n):
                                        err_msg=k)
 
 
-def test_sketch_kernel_matches_numpy_oracle():
+def test_sketch_kernel_matches_numpy_oracle(interpret):
     from repro.core.sketch import FailSlowSketch, SketchParams, split_key
     from repro.kernels.sketch_update import ops as O
     p = SketchParams(d=2, m=64, H=4, L=16)
@@ -53,7 +79,7 @@ def test_sketch_kernel_matches_numpy_oracle():
     st = O.insert(O.make_state(p), jnp.asarray(lo), jnp.asarray(hi),
                   jnp.asarray(dur), jnp.asarray(dur * 2),
                   jnp.asarray(np.arange(n, dtype=np.float32)),
-                  params=p, impl="pallas")
+                  params=p, impl="pallas", interpret=interpret)
     pats = {q.key & 0x7FFFFFFF: q for q in O.patterns(st)}
     exp = {int(k) & 0x7FFFFFFF: v for k, v in oracle.stage2.items()}
     assert set(pats) == set(exp)
@@ -173,7 +199,7 @@ def _assert_pattern_parity(got, exp, *, check_stats=True):
 
 
 @pytest.mark.parametrize("impl", ["batched", "pallas"])
-def test_sketch_drain_matches_oracle_under_eviction(impl):
+def test_sketch_drain_matches_oracle_under_eviction(impl, interpret):
     """Forced Stage-2 eviction pressure (small L, many distinct promoted
     keys): the drained-eviction stream preserves every FIFO victim, so
     merged patterns() — live + drained — equals the numpy oracle's.
@@ -193,16 +219,56 @@ def test_sketch_drain_matches_oracle_under_eviction(impl):
     st, dr = O.insert(O.make_state(p), jnp.asarray(lo), jnp.asarray(hi),
                       jnp.asarray(dur), jnp.asarray(dur * 2),
                       jnp.asarray(ts), params=p, impl=impl,
-                      drain=O.make_drain(n))
+                      drain=O.make_drain(n), interpret=interpret)
     assert int(np.asarray(dr["d_n"])) == oracle.n_evicted
     _assert_pattern_parity(O.patterns(st, dr),
                            oracle.patterns(include_drained=True))
     # drain-less call still returns the live-only view, unchanged state
     st2 = O.insert(O.make_state(p), jnp.asarray(lo), jnp.asarray(hi),
                    jnp.asarray(dur), jnp.asarray(dur * 2),
-                   jnp.asarray(ts), params=p, impl=impl)
+                   jnp.asarray(ts), params=p, impl=impl,
+                   interpret=interpret)
     for k in st2:
         assert np.array_equal(np.asarray(st[k]), np.asarray(st2[k])), k
+
+
+@pytest.mark.parametrize("d,m,H,L,n,block,cap", [
+    (2, 1024, 4, 2048, 600, 256, 600),  # the pod detector's geometry
+    (2, 16, 1, 4, 400, 128, 5),         # drain saturates past 5 rows
+    (3, 8, 2, 8, 257, 256, 257),        # one record past a block edge
+])
+def test_sketch_kernel_smem_layout_matches_batched(d, m, H, L, n, block,
+                                                   cap, interpret):
+    """The chip layout — trace blocks, flattened Stage-1 tables and
+    scalars in SMEM, Stage-2 and drain rows as masked VMEM lane stores —
+    carries state and drain exactly like the batched scan, including a
+    drain too small for its evictions."""
+    from repro.core.sketch import SketchParams, split_key
+    from repro.kernels.sketch_update import batched as V, ops as O
+    p = SketchParams(d=d, m=m, H=H, L=L)
+    rng = np.random.default_rng(d * 1000 + L)
+    keys = rng.integers(0, 300, size=n).astype(np.int64) * 0x9E3779B9
+    lo, hi = split_key(keys)
+    dur = rng.random(n).astype(np.float32)
+    val = (rng.random(n) * 5).astype(np.float32)
+    t = np.cumsum(rng.random(n)).astype(np.float32)
+    args = tuple(jnp.asarray(x) for x in (lo, hi, dur, val, t))
+    want = V.insert_batch_drained(O.make_state(p), O.make_drain(cap),
+                                  *args, H=p.H)
+    got = O.insert(O.make_state(p), *args, params=p, impl="pallas",
+                   block=block, drain=O.make_drain(cap),
+                   interpret=interpret)
+    for w_part, g_part in zip(want, got):
+        for k in w_part:
+            a, b = np.asarray(w_part[k]), np.asarray(g_part[k])
+            assert a.shape == b.shape, k
+            if a.dtype.kind == "i":
+                assert np.array_equal(a, b), k
+            else:
+                np.testing.assert_allclose(a, b, rtol=1e-6, atol=1e-6,
+                                           err_msg=k)
+    if cap < n:
+        assert int(np.asarray(got[1]["d_n"])) <= cap
 
 
 @settings(max_examples=8, deadline=None)
@@ -298,14 +364,15 @@ def test_sketch_run_path_promotion_and_steal_branches():
     (1, 1, 384, 8, 4, 64, True, None, jnp.float32),
     (1, 128, 128, 2, 2, 64, True, None, jnp.bfloat16),
 ])
-def test_flash_attention_sweep(b, s, t, hq, hk, d, causal, win, dtype):
+def test_flash_attention_sweep(b, s, t, hq, hk, d, causal, win, dtype,
+                              interpret):
     from repro.kernels.flash_attention.ops import gqa_attention
     ks = jax.random.split(jax.random.PRNGKey(0), 3)
     q = jax.random.normal(ks[0], (b, s, hq, d)).astype(dtype)
     k = jax.random.normal(ks[1], (b, t, hk, d)).astype(dtype)
     v = jax.random.normal(ks[2], (b, t, hk, d)).astype(dtype)
     a = gqa_attention(q, k, v, causal=causal, window=win, impl="pallas",
-                      q_block=64, kv_block=64)
+                      q_block=64, kv_block=64, interpret=interpret)
     r = gqa_attention(q, k, v, causal=causal, window=win, impl="ref")
     tol = 2e-2 if dtype == jnp.bfloat16 else 2e-5
     np.testing.assert_allclose(np.asarray(a, np.float32),
@@ -321,8 +388,12 @@ def test_flash_attention_sweep(b, s, t, hq, hk, d, causal, win, dtype):
     (2, 96, 4, 32, 2, 16, 32),
     (1, 200, 2, 16, 1, 8, 64),
     (2, 64, 8, 8, 4, 8, 16),
+    # the chip's layout: lane-wide chunks of the [BH, 1, S] dt row, a
+    # padded tail, and a per-head decay read from SMEM
+    (1, 300, 3, 64, 1, 128, 128),
+    (2, 256, 2, 32, 2, 16, 128),
 ])
-def test_ssd_kernel_sweep(b, s, h, p, g, n, chunk):
+def test_ssd_kernel_sweep(b, s, h, p, g, n, chunk, interpret):
     from repro.kernels.ssd_scan.ops import ssd
     ks = jax.random.split(jax.random.PRNGKey(1), 5)
     x = jax.random.normal(ks[0], (b, s, h, p)) * 0.5
@@ -330,7 +401,8 @@ def test_ssd_kernel_sweep(b, s, h, p, g, n, chunk):
     a = -jnp.exp(jax.random.normal(ks[2], (h,)) * 0.3)
     bb = jax.random.normal(ks[3], (b, s, g, n)) * 0.4
     cc = jax.random.normal(ks[4], (b, s, g, n)) * 0.4
-    yp, sp = ssd(x, dt, a, bb, cc, impl="pallas", chunk=chunk)
+    yp, sp = ssd(x, dt, a, bb, cc, impl="pallas", chunk=chunk,
+                 interpret=interpret)
     yr, sr = ssd(x, dt, a, bb, cc, impl="ref")
     np.testing.assert_allclose(np.asarray(yp), np.asarray(yr), atol=2e-4,
                                rtol=2e-4)
@@ -359,7 +431,7 @@ def test_model_ssd_matches_recurrence():
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("n", [40, 130, 260])
-def test_failrank_step_sweep(n):
+def test_failrank_step_sweep(n, interpret):
     from repro.kernels.failrank_step.kernel import failrank_step
     from repro.kernels.failrank_step.ref import failrank_step_ref
     rng = np.random.default_rng(n)
@@ -369,14 +441,14 @@ def test_failrank_step_sweep(n):
     s = rng.random(n).astype(np.float32)
     s0 = rng.random(n).astype(np.float32)
     sp, lp = failrank_step(jnp.asarray(w), jnp.asarray(l), jnp.asarray(s),
-                           jnp.asarray(s0))
+                           jnp.asarray(s0), interpret=interpret)
     sr, lr = failrank_step_ref(jnp.asarray(w), jnp.asarray(l),
                                jnp.asarray(s), jnp.asarray(s0))
     np.testing.assert_allclose(np.asarray(sp), np.asarray(sr), atol=1e-5)
     np.testing.assert_allclose(np.asarray(lp), np.asarray(lr), atol=1e-5)
 
 
-def test_failrank_dense_matches_coo_pipeline():
+def test_failrank_dense_matches_coo_pipeline(interpret):
     from repro.core.failrank import failrank
     from repro.core.failures import FailSlow
     from repro.core.graph import build_workload
@@ -386,5 +458,6 @@ def test_failrank_dense_matches_coo_pipeline():
     sloth = Sloth(build_workload("darknet19"), Mesh2D(4))
     v = sloth.detect([FailSlow("core", 5, 1.0, 8.0)], seed=0)
     r_coo = failrank(v.mcg)
-    _, s_raw, _, _ = failrank_dense(v.mcg, impl="pallas")
+    _, s_raw, _, _ = failrank_dense(v.mcg, impl="pallas",
+                                    interpret=interpret)
     np.testing.assert_allclose(s_raw, r_coo.raw_node_scores, atol=1e-4)

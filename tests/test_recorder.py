@@ -129,6 +129,27 @@ def test_record_impl_parity_under_eviction(deployment):
     _assert_recorder_parity(a, b)
 
 
+@pytest.mark.parametrize("impl", ["ref", "batched"])
+def test_recorder_reports_state_device(deployment, impl):
+    """RecorderOutput.device names where the sketch state lived — the
+    backend's device for the batched path (one-shot and streaming), the
+    host for the numpy oracle — so a chip run can prove the recorder ran
+    on the chip."""
+    import jax
+
+    from repro.core.streaming import StreamingRecorder, split_sim
+    sloth, sim = deployment
+    hop = sloth.sim_cfg.hop_latency
+    want = jax.default_backend() if impl == "batched" else "host"
+    assert record(sim, sloth.cfg.sketch, hop_latency=hop,
+                  impl=impl).device == want
+    stream = StreamingRecorder(sloth.cfg.sketch, hop_latency=hop, impl=impl)
+    assert stream.output().device == "host"      # nothing recorded yet
+    for chunk in split_sim(sim, 2):
+        stream.observe(chunk)
+    assert stream.output().device == want
+
+
 def test_record_unknown_impl_rejected(deployment):
     sloth, sim = deployment
     with pytest.raises(ValueError, match="unknown recorder impl"):

@@ -360,3 +360,57 @@ def test_engine_split_timing_series():
     assert engine.decode_times == [dt for k, dt in events if k == "decode"]
     assert engine.prefill_times \
         == [dt for k, dt in events if k == "prefill"]
+
+
+class _Pending:
+    """A step result whose ``block_until_ready`` waits, as a device
+    result does while its step still runs on the chip."""
+
+    def __init__(self, value, wait_s):
+        self.value = value
+        self.wait_s = wait_s
+
+    def block_until_ready(self):
+        import time
+        time.sleep(self.wait_s)
+        return self
+
+    def __array__(self, dtype=None, copy=None):
+        return np.asarray(self.value, dtype=dtype)
+
+
+@pytest.mark.parametrize("kind", ["prefill", "decode"])
+def test_engine_step_timing_covers_device_wait(kind):
+    """Regression: the engine read the clock right after dispatching a
+    step, so on a TPU ``decode_times`` (and the serve ``--telemetry``
+    signal) timed the enqueue.  Each timing must end only once the
+    step's next tokens are ready."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.configs.base import get_config
+    from repro.models import transformer as T
+    from repro.serving.engine import EngineConfig, Request, ServeEngine
+
+    wait = 0.05
+    cfg = get_config("smollm-135m", smoke=True)
+    params = T.init_model(cfg, jax.random.PRNGKey(0), dtype=jnp.float32)
+    events = []
+    engine = ServeEngine(
+        cfg, params, EngineConfig(batch=2, cache_len=32),
+        step_hook=lambda k, dt: events.append((k, dt)))
+    for name in ("prefill", "decode"):
+        def wrapped(p, toks, *rest, step=getattr(engine, "_" + name),
+                    slow=name == kind):
+            out = step(p, np.asarray(toks), *rest)
+            return ((_Pending(out[0], wait) if slow else out[0]),) \
+                + tuple(out[1:])
+        setattr(engine, "_" + name, wrapped)
+    for _ in range(2):              # the second round runs compiled
+        events.clear()
+        for i in range(2):
+            engine.submit(Request(i, np.arange(1, 5, dtype=np.int32),
+                                  max_new=2))
+        assert len(engine.run()) == 2
+    timed = [dt for k, dt in events if k == kind]
+    assert timed and min(timed) >= wait

@@ -8,9 +8,8 @@ pytestmark = pytest.mark.filterwarnings("ignore::DeprecationWarning")
 
 
 def test_training_loss_decreases(tmp_path):
-    """Memorisation check: a fixed batch must be learnable well below the
-    uniform-entropy floor (the synthetic stream itself is uniform, so the
-    launcher integration test asserts continuity, not convergence)."""
+    """Memorisation check: a fixed uniform batch must be learnable well
+    below the uniform-entropy floor."""
     import jax
     import jax.numpy as jnp
     from repro.configs.base import get_config
@@ -32,6 +31,80 @@ def test_training_loss_decreases(tmp_path):
         params, opt, loss, _ = step(params, opt, tokens)
         losses.append(float(loss))
     assert losses[-1] < losses[0] - 0.5, (losses[0], losses[-1])
+
+
+def test_launcher_stream_is_learnable():
+    """The launcher's own token stream has a law to fit: over 24 steps the
+    loss falls.  A uniform stream cannot fall below ln(vocab), so a
+    training run on it could not show that the loop learns."""
+    from repro.launch.train import main
+    losses = main(["--arch", "smollm-135m", "--smoke", "--steps", "24",
+                   "--batch", "8", "--seq", "128", "--lr", "1e-3",
+                   "--log-every", "100"])
+    assert all(np.isfinite(losses))
+    assert losses[-1] < losses[0] - 0.1, (losses[0], losses[-1])
+
+
+def test_token_stream_is_zipf_like():
+    from repro.data.pipeline import DataConfig, TokenPipeline
+    vocab = 49152
+    toks = TokenPipeline(DataConfig(vocab=vocab, batch=16, seq=1024)) \
+        .batch_at(0).ravel()
+    assert toks.min() >= 0 and toks.max() < vocab
+    counts = np.bincount(toks, minlength=vocab)
+    p = counts[counts > 0] / toks.size
+    entropy = float(-(p * np.log(p)).sum())
+    assert entropy < np.log(vocab) - 2.0          # far from uniform
+    assert counts[:16].sum() > counts[-4096:].sum()   # head outweighs tail
+
+
+def test_bench_harness_fails_when_a_bench_raises(monkeypatch, capsys):
+    """A bench that raises still prints its row, and the harness exits
+    nonzero: a broken phase cannot pass as a run."""
+    import importlib.util
+    from pathlib import Path
+    root = Path(__file__).resolve().parents[1]
+    spec = importlib.util.spec_from_file_location(
+        "bench_run", root / "benchmarks" / "run.py")
+    run = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(run)
+    import benchmarks.paper as paper
+    import repro.compile_cache as cc
+
+    def bench_boom():
+        raise RuntimeError("boom")
+
+    monkeypatch.setattr(paper, "ALL", [bench_boom])
+    monkeypatch.setattr(cc, "enable_compile_cache", lambda: "")
+    monkeypatch.setenv("BENCH_ONLY", "bench_boom")
+    with pytest.raises(SystemExit) as exc:
+        run.main()
+    assert exc.value.code not in (None, 0)
+    assert "bench_boom,0,EXCEPTION:RuntimeError:boom" in \
+        capsys.readouterr().out
+
+
+def test_compile_cache_placed_from_outside(monkeypatch, tmp_path):
+    """JAX_COMPILATION_CACHE_DIR wins and the code sets no other
+    directory; without it the cache is one fixed, git-ignored directory
+    of the checkout."""
+    import jax
+    from pathlib import Path
+
+    from repro import compile_cache
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert compile_cache.enable_compile_cache() == str(tmp_path)
+    assert jax.config.jax_compilation_cache_dir == before
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    root = Path(__file__).resolve().parents[1]
+    try:
+        path = compile_cache.enable_compile_cache()
+        assert path == str(root / ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == path
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+    assert ".jax_cache/" in (root / ".gitignore").read_text().split()
 
 
 def test_crash_resume_continuity(tmp_path):
