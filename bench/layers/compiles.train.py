@@ -1,0 +1,6 @@
+"""Backend compiles and compile-cache loads inside the training window,
+from ``jax.monitoring``: there should be none."""
+
+
+def read(run):
+    return run.counter("compiles")
