@@ -14,7 +14,7 @@ contract machine-checked over ``core/``, ``kernels/``, ``mitigate/``,
   and ``datetime.now/utcnow``: wall-clock reads inside the pipeline
   make outputs run-dependent.  Telemetry that *reports* wall time (and
   never feeds results) is allowlisted with a ``# lint: allow-wallclock``
-  marker on the offending line — ``campaign._wall_clock`` is the one
+  marker on the offending line — ``core.spans.clock`` is the one
   blessed reader.
 * ``unregistered-detector`` — a public detector-shaped class (a ``name``
   string attribute plus both ``prepare`` and ``analyse`` methods) or

@@ -198,7 +198,6 @@ import functools
 import math
 import multiprocessing
 import os
-import time
 import warnings
 from concurrent.futures import ProcessPoolExecutor, ThreadPoolExecutor
 
@@ -218,6 +217,7 @@ from .metrics import (CampaignMetrics, DetectorOutcome, MitigationOutcome,
 from .routing import build_topology, parse_topology_spec, topology_spec
 from .simulator import SimResult, simulate, simulate_mitigated
 from .sloth import Sloth, SlothConfig, SlothDetector
+from .spans import clock
 # submodule import (not the package) so a partially-initialised
 # repro.mitigate package during circular-ish import chains still resolves
 from ..mitigate.policy import (get_policy, instantiate_policy,
@@ -236,15 +236,6 @@ EXECUTORS = ("thread", "process")
 
 #: Kinds a concrete failure may take (everything except 'none').
 FAILURE_KINDS = ("core", "link", "router")
-
-
-def _wall_clock() -> float:
-    """The campaign's one blessed wall-clock read, feeding the
-    ``compare=False`` wall-time telemetry only (``wall_time_stats``) —
-    never a scenario outcome, verdict, or RNG stream.  Centralised so
-    the determinism lint (``repro.analysis.lints`` rule ``wallclock``)
-    has exactly one allowlisted reader to audit."""
-    return time.perf_counter()  # lint: allow-wallclock
 
 
 def _normalise_kind(kind) -> str:
@@ -905,7 +896,7 @@ def _mitigate_scenario(dep: Deployment, failures, sim: SimResult,
     sloth = dep.sloth
     healthy_t = float(dep.healthy.total_time)
     failed_t = float(sim.total_time)
-    t0 = _wall_clock()
+    t0 = clock()
     plan = policy.plan(verdict, sloth.mapped, sloth.mesh, sloth.cfg)
     if not plan.acted:
         return MitigationOutcome(
@@ -913,7 +904,7 @@ def _mitigate_scenario(dep: Deployment, failures, sim: SimResult,
             correct=correct, exclude_cores=(), avoid_links=(),
             healthy_time=healthy_t, failed_time=failed_t,
             mitigated_time=failed_t, switch_time=None,
-            wall_time=_wall_clock() - t0)
+            wall_time=clock() - t0)
     mitigated = policy.apply(plan, sloth.mapped, sloth.cfg)
     sim_cfg = dataclasses.replace(sloth.sim_cfg, seed=sim_seed)
     from_t = float(switch_time) if switch_time is not None else 0.0
@@ -930,7 +921,7 @@ def _mitigate_scenario(dep: Deployment, failures, sim: SimResult,
         correct=correct, exclude_cores=plan.exclude_cores,
         avoid_links=plan.avoid_links, healthy_time=healthy_t,
         failed_time=failed_t, mitigated_time=mit_t,
-        switch_time=switch_time, wall_time=_wall_clock() - t0)
+        switch_time=switch_time, wall_time=clock() - t0)
 
 
 def run_scenario(grid: CampaignGrid, s: Scenario, dep: Deployment,
@@ -960,16 +951,16 @@ def run_scenario(grid: CampaignGrid, s: Scenario, dep: Deployment,
     full restart."""
     failures, sim_seed = materialise(grid, s, dep)
     policies = [instantiate_policy(p) for p in mitigation]
-    t0 = _wall_clock()
+    t0 = clock()
     sim = dep.sloth.run(list(failures) if failures else None, seed=sim_seed)
-    sim_wall = _wall_clock() - t0
+    sim_wall = clock() - t0
     mesh = dep.sloth.mesh
     results = []
     mitigations: list[MitigationOutcome] = []
     compression = 0.0
     total_time = float(sim.total_time)
     for det in dep.detectors:
-        t1 = _wall_clock()
+        t1 = clock()
         latency = None
         first_flag = None
         streamed = streaming > 0 and hasattr(det, "stream_analyse")
@@ -981,7 +972,7 @@ def run_scenario(grid: CampaignGrid, s: Scenario, dep: Deployment,
                            if first_flag is not None else math.inf)
         else:
             v = det.analyse(sim)
-        wall = _wall_clock() - t1
+        wall = clock() - t1
         matched, rank, ranks, _ = judge_verdict(v, failures, mesh)
         if compression == 0.0 and v.recorder is not None:
             compression = float(v.recorder.compression_ratio)

@@ -25,6 +25,7 @@ import math
 import numpy as np
 
 from . import probes as P
+from . import spans
 from .routing import Topology
 from .sketch import Pattern
 
@@ -89,6 +90,7 @@ def assign_window(t_mid: np.ndarray, total_time: float,
     return np.clip(w, 0, n_windows - 1)
 
 
+@spans.traced("sloth.detect.cores")
 def detect_cores(patterns: list[Pattern], total_time: float,
                  n_windows: int = 4, z_flag: float = 2.5,
                  min_group: int = 3,
@@ -102,6 +104,7 @@ def detect_cores(patterns: list[Pattern], total_time: float,
     fail-slow outlier against its full-rate peers.  All-ones (or ``None``)
     leaves the historical rates bit-identical.
     """
+    spans.count("patterns", len(patterns))
     if not patterns:
         return []
     keys = np.array([p.key for p in patterns], dtype=np.int64)
@@ -162,6 +165,7 @@ class LinkInference:
     candidates: list[LinkCandidate]
 
 
+@spans.traced("sloth.detect.links.em")
 def em_link_inverse_bw(A: np.ndarray, T: np.ndarray, V: np.ndarray,
                        weights: np.ndarray, hop_latency: float = 0.0,
                        iters: int = 60) -> np.ndarray:
@@ -196,6 +200,7 @@ def em_link_inverse_bw(A: np.ndarray, T: np.ndarray, V: np.ndarray,
     return theta
 
 
+@spans.traced("sloth.detect.links")
 def detect_links(patterns: list[Pattern], mesh: Topology, total_time: float,
                  n_windows: int = 4, hop_latency: float = 50e-9,
                  ratio_flag: float = 3.0, em_iters: int = 60) -> LinkInference:
@@ -236,6 +241,7 @@ def detect_links(patterns: list[Pattern], mesh: Topology, total_time: float,
         return LinkInference(theta, observed, cands)
     pairs = [(int(src[i]), int(dst[i])) for i in inter]
     A = mesh.path_matrix(pairs)                     # [events, links]
+    spans.count("events", A.shape[0])
     T = np.maximum(min_T[inter] - hop_latency * A.sum(axis=1), 1e-12)
     V = mean_V[inter]
     W = cnt[inter]

@@ -24,6 +24,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from . import spans
 from .mcg import MCG
 
 
@@ -71,9 +72,10 @@ def _failrank_iterate(s0, l0, w, src, dst, lam, alpha, beta, gamma, eps,
         return s_new, l_new, k + 1, delta, res
 
     res0 = jnp.full((max_iters,), jnp.nan, dtype=s0.dtype)
-    s, l, k, delta, res = jax.lax.while_loop(
-        cond, body, (s0, l0, jnp.int32(0), jnp.asarray(jnp.inf, s0.dtype),
-                     res0))
+    with jax.named_scope("failrank.iterate"):
+        s, l, k, delta, res = jax.lax.while_loop(
+            cond, body, (s0, l0, jnp.int32(0),
+                         jnp.asarray(jnp.inf, s0.dtype), res0))
     return s, l, k, res
 
 
@@ -87,6 +89,7 @@ def _softmax_per_level(scores: np.ndarray, levels: np.ndarray) -> np.ndarray:
     return out
 
 
+@spans.traced("sloth.failrank.iterate")
 def failrank(mcg: MCG, params: FailRankParams = FailRankParams())\
         -> FailRankResult:
     if len(mcg.edge_src) == 0:
@@ -109,9 +112,11 @@ def failrank(mcg: MCG, params: FailRankParams = FailRankParams())\
     edge_levels = np.minimum(mcg.edge_src // mcg.mesh.n_cores,
                              mcg.n_windows - 1)
     edge_soft = _softmax_per_level(l, edge_levels)
+    spans.count("iters", int(k))
     return FailRankResult(node_soft, edge_soft, s, l, int(k), res)
 
 
+@spans.traced("sloth.failrank.attribute")
 def attribute_links(mcg: MCG, result: FailRankResult,
                     link_theta: np.ndarray | None = None) -> np.ndarray:
     """Fold MCG edge scores back onto physical links.

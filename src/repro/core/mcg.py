@@ -17,6 +17,7 @@ import dataclasses
 
 import numpy as np
 
+from . import spans
 from .detection import (CoreCandidate, LinkInference, assign_window)
 from .routing import Topology
 from .sketch import Pattern
@@ -52,6 +53,7 @@ class MCG:
 DRAM_EDGE_WEIGHT = 0.1   # relative weight of inter-level (memory) edges
 
 
+@spans.traced("sloth.failrank.mcg")
 def build_mcg(comm_patterns: list[Pattern], mesh: Topology, total_time: float,
               core_cands: list[CoreCandidate], link_inf: LinkInference,
               n_windows: int = 4) -> MCG:

@@ -43,6 +43,7 @@ import math
 import numpy as np
 
 from . import probes as P
+from . import spans
 from .recorder import (RECORDER_IMPLS, RecorderOutput, comm_runs,
                        comp_runs, state_platform)
 from .simulator import SimResult
@@ -101,8 +102,10 @@ class _SketchStream:
     """One side (comp or comm) of the streaming recorder: persistent
     sketch state + accumulated drained partials + record accounting."""
 
-    def __init__(self, params: SketchParams, impl: str, key_tag: int):
+    def __init__(self, params: SketchParams, impl: str, key_tag: int,
+                 side: str):
         self.params = params
+        self.side = side
         self.impl = impl
         self.key_tag = key_tag
         self.n_records = 0
@@ -116,8 +119,15 @@ class _SketchStream:
     def insert(self, keys, reps, durs, vals, t0s, dts) -> None:
         if not len(keys):
             return
+        with spans.span("sloth.record.scan", side=self.side):
+            spans.count("runs", len(keys))
+            self._insert(keys, reps, durs, vals, t0s, dts)
+
+    def _insert(self, keys, reps, durs, vals, t0s, dts) -> None:
         if self.impl == "ref":
+            n0 = self.sk.n_evicted
             self.sk.insert_runs(keys, reps, durs, vals, t0s, dts)
+            spans.count("drained", self.sk.n_evicted - n0)
             return
         # lazy jax import, mirroring recorder._sketch_runs_batched
         import jax.numpy as jnp
@@ -142,7 +152,9 @@ class _SketchStream:
             params=self.params)
         for pat in sketch_ops.drain_patterns(drain, key_tag=self.key_tag):
             accumulate_pattern(self.drained, pat)
-        self.n_drained += int(np.asarray(drain["d_n"]))
+        n = int(np.asarray(drain["d_n"]))
+        self.n_drained += n
+        spans.count("drained", n)
 
     def patterns(self) -> list[Pattern]:
         if self.impl == "ref":
@@ -215,35 +227,42 @@ class StreamingRecorder:
         self.packet_bytes = packet_bytes
         self.max_packets = max_packets
         self.hop_latency = hop_latency
-        self._comp = _SketchStream(params, impl, P.COMP_KEY_TAG)
+        self._comp = _SketchStream(params, impl, P.COMP_KEY_TAG, "comp")
         self._comm = _SketchStream(comm_params or params, impl,
-                                   P.COMM_KEY_TAG)
+                                   P.COMM_KEY_TAG, "comm")
         self.elapsed = 0.0
         self.n_chunks = 0
 
+    @spans.traced("sloth.record")
     def observe(self, chunk: SimResult) -> None:
         """Absorb one trace chunk into the resident sketches."""
         self.n_chunks += 1
         comp = chunk.comp
         if len(comp["core"]):
-            runs = comp_runs(comp, self.instr_per_task)
+            with spans.span("sloth.record.runs", side="comp"):
+                runs = comp_runs(comp, self.instr_per_task)
             self._comp.insert(*runs)
             self._comp.n_records += len(runs[0]) * self.instr_per_task
             self.elapsed = max(self.elapsed, float(np.max(comp["t_end"])))
         comm = chunk.comm
         if len(comm["src"]):
-            runs = comm_runs(comm, self.packet_bytes, self.max_packets,
-                             self.hop_latency)
+            with spans.span("sloth.record.runs", side="comm"):
+                runs = comm_runs(comm, self.packet_bytes, self.max_packets,
+                                 self.hop_latency)
             self._comm.insert(*runs)
             self._comm.n_records += int(runs[1].sum())
             self.elapsed = max(self.elapsed,
                                float(np.max(comm["t_arrive"])))
 
+    @spans.traced("sloth.record")
     def output(self) -> RecorderOutput:
         """Cumulative recorder output (same accounting as ``record``)."""
+        with spans.span("sloth.record.decode"):
+            comp, comm = self._comp.patterns(), self._comm.patterns()
+            spans.count("patterns", len(comp) + len(comm))
         return RecorderOutput(
-            comp_patterns=self._comp.patterns(),
-            comm_patterns=self._comm.patterns(),
+            comp_patterns=comp,
+            comm_patterns=comm,
             raw_comp_bytes=self._comp.n_records * P.COMP_RECORD_BYTES,
             raw_comm_bytes=self._comm.n_records * P.COMM_RECORD_BYTES,
             sketch_comp_bytes=self._comp.compressed_bytes(),

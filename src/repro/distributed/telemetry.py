@@ -36,6 +36,7 @@ import dataclasses
 
 import numpy as np
 
+from ..core import spans
 from ..core.detection import detect_cores, detect_links
 from ..core.detectors import Verdict
 from ..core.failrank import FailRankParams, attribute_links, failrank
@@ -239,17 +240,18 @@ class PodDetector:
         if max(max_core, max_link) < cfg.detect_threshold:
             return PodVerdict(False, None, None, 0.0, "none")
 
-        mcg = build_mcg(rec.comm_patterns, self.mesh, total_time,
-                        cores, links, 4)
-        fr = failrank(mcg, FailRankParams())
-        core_fr = np.zeros(n_cores)
-        core_nodes = fr.raw_node_scores[:mcg.n_windows * n_cores]
-        for w in range(mcg.n_windows):
-            core_fr = np.maximum(
-                core_fr, core_nodes[w * n_cores:(w + 1) * n_cores])
-        core_fr /= max(core_fr.max(), 1e-12)
-        link_fr = attribute_links(mcg, fr, links.theta)
-        link_fr /= max(link_fr.max(), 1e-12)
+        with spans.span("sloth.failrank"):
+            mcg = build_mcg(rec.comm_patterns, self.mesh, total_time,
+                            cores, links, 4)
+            fr = failrank(mcg, FailRankParams())
+            core_fr = np.zeros(n_cores)
+            core_nodes = fr.raw_node_scores[:mcg.n_windows * n_cores]
+            for w in range(mcg.n_windows):
+                core_fr = np.maximum(
+                    core_fr, core_nodes[w * n_cores:(w + 1) * n_cores])
+            core_fr /= max(core_fr.max(), 1e-12)
+            link_fr = attribute_links(mcg, fr, links.theta)
+            link_fr /= max(link_fr.max(), 1e-12)
         core_scores = core_ev * (0.5 + core_fr)
         link_scores = link_ev * (0.5 + link_fr)
 
@@ -270,9 +272,11 @@ class PodDetector:
                      impl=cfg.recorder_impl)
         return self._verdict_from(rec, sim.total_time)
 
+    @spans.traced("pod.verdict", root="verdict")
     def observe(self, window: SimResult) -> PodVerdict:
         """Absorb one telemetry window into the resident sketch and
-        return the verdict over the cumulative stream."""
+        return the verdict over the cumulative stream (span
+        ``pod.verdict``, which opens a ``verdict`` id)."""
         if self._stream is None:
             self._stream = StreamingRecorder(
                 self.cfg.sketch, instr_per_task=1, hop_latency=0.0,
@@ -391,6 +395,10 @@ class StepTelemetry:
         ``None`` if nothing is buffered."""
         if not self._buf:
             return None
+        with spans.span("telemetry.flush"):
+            return self._flush()
+
+    def _flush(self) -> PodVerdict:
         dts = np.asarray(self._dts)
         # baseline and peer noise describe the *healthy* steady state:
         # steps ≥ 2× the raw median are treated as slowdown candidates
@@ -425,15 +433,17 @@ class StepTelemetry:
             np.full(2 - len(lead), baseline), lead, buf,
             [baseline, baseline]])
         chip0 = np.array([np.median(padded[i:i + 5]) for i in range(n)])
-        window = self.pod.run_steps(
-            len(self._buf), t0=self._t, step0=self._step,
-            chip0_durs=chip0, base=baseline, jitter=noise)
+        with spans.span("telemetry.flush.simulate"):
+            window = self.pod.run_steps(
+                len(self._buf), t0=self._t, step0=self._step,
+                chip0_durs=chip0, base=baseline, jitter=noise)
         self._step += len(self._buf)
         self._t += float(window.total_time)
         self._buf = []
         v = self.detector.observe(window)
         self.verdicts.append(v)
-        self.plans.append(self.policy.plan(v))
+        with spans.span("telemetry.flush.plan"):
+            self.plans.append(self.policy.plan(v))
         return v
 
     @property

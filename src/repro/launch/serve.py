@@ -1,9 +1,8 @@
 """Serving launcher: batched prefill+decode with SLOTH telemetry hooks.
 
-Decode percentiles come from the engine's dedicated ``decode_times``
-series — the old single ``step_times`` list interleaved every batch's
-prefill with its decode steps, and dropping only index 0 left later
-batches' (much slower) prefills inflating the "decode" p50/p99.
+Decode percentiles come from the engine's ``decode_times`` series, kept
+apart from ``prefill_times``: a batch's prefill is much slower than its
+decode steps and would inflate the "decode" p50/p99.
 
 ``--telemetry`` taps the engine's per-step hook: decode step timings
 stream into the pod detector every window
@@ -19,7 +18,6 @@ CPU example:
 from __future__ import annotations
 
 import argparse
-import time
 
 import jax
 import jax.numpy as jnp
@@ -27,6 +25,7 @@ import numpy as np
 
 from ..compile_cache import enable_compile_cache
 from ..configs.base import get_config
+from ..core import spans
 from ..distributed.telemetry import PodTelemetryConfig, StepTelemetry
 from ..models import transformer as T
 from ..serving.engine import EngineConfig, Request, ServeEngine
@@ -86,9 +85,9 @@ def main(argv=None):
         n = int(rng.integers(2, args.prompt_len + 1))
         engine.submit(Request(i, rng.integers(0, cfg.vocab, size=n)
                               .astype(np.int32), max_new=args.max_new))
-    t0 = time.perf_counter()  # lint: allow-wallclock (reported only)
+    t0 = spans.clock()
     done = engine.run(enc_frames=enc_frames)
-    wall = time.perf_counter() - t0  # lint: allow-wallclock
+    wall = spans.clock() - t0
     tok = sum(len(r.out_tokens) for r in done)
     print(f"served {len(done)} requests, {tok} tokens, {wall:.1f}s "
           f"({tok / max(wall, 1e-9):.1f} tok/s)")

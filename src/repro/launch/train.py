@@ -15,6 +15,12 @@ Features exercised end-to-end (CPU-scale here, pod-scale by mesh swap):
     (training itself is unperturbed) so the detection path is
     demonstrable end-to-end; ``--expect-flagged`` turns "the injected
     slowdown was flagged" into an exit-code assertion (the CI smoke).
+  * spans (:mod:`repro.core.spans`): every step is a ``train.step`` span
+    with children ``train.data`` (the next batch onto the device),
+    ``train.dispatch`` (the jitted step's call), ``train.sync`` (the wait
+    for the loss), and ``train.telemetry``, ``train.log``, ``train.ckpt``
+    where the step does that work.  The telemetry signal is the step's
+    ``dispatch`` start to its ``sync`` end on the one clock.
 
 Usage (CPU example):
   PYTHONPATH=src python -m repro.launch.train --arch smollm-135m --smoke \
@@ -29,7 +35,6 @@ from __future__ import annotations
 
 import argparse
 import dataclasses
-import time
 
 import jax
 import jax.numpy as jnp
@@ -38,6 +43,7 @@ import numpy as np
 from ..checkpoint import store
 from ..compile_cache import enable_compile_cache
 from ..configs.base import get_config
+from ..core import spans
 from ..data.pipeline import DataConfig, TokenPipeline
 from ..distributed.telemetry import PodTelemetryConfig, StepTelemetry
 from ..models import transformer as T
@@ -118,60 +124,46 @@ def main(argv=None):
                                jnp.float32)
 
     losses = []
-    t_begin = time.perf_counter()  # lint: allow-wallclock (telemetry)
+    t_begin = spans.clock()
     for step in range(start_step, args.steps):
-        tokens = jnp.asarray(next(pipe))
-        t0 = time.perf_counter()  # lint: allow-wallclock (measured dt)
-        if cfg.enc_dec:
-            params, opt_state, loss, gnorm = train_step(
-                params, opt_state, tokens, enc_frames)
-        else:
-            params, opt_state, loss, gnorm = train_step(
-                params, opt_state, tokens)
-        loss = float(loss)
-        losses.append(loss)
-        dt = time.perf_counter() - t0  # lint: allow-wallclock
-        if telemetry is not None:
-            reported = dt
-            if args.inject_slow_at is not None and \
-                    args.inject_slow_at <= step \
-                    < args.inject_slow_at + args.inject_slow_steps:
-                reported *= args.inject_slow_factor
-            verdict = telemetry.record_step(reported)
-            if verdict is not None:
-                plan = telemetry.plans[-1]
-                if verdict.flagged:
-                    print(f"[telemetry] step {step}: FLAGGED "
-                          f"{verdict.kind} {verdict.location} "
-                          f"severity {verdict.severity:.1f} -> "
-                          f"{plan['action']}")
-                    if plan.get("exclude_cores") or plan.get("avoid_links"):
-                        # registry-backed plan (remap/reroute on the pod
-                        # mesh): the resource edits the restart applies
-                        print(f"[telemetry] {plan['policy']} plan: "
-                              f"exclude cores "
-                              f"{list(plan.get('exclude_cores', ()))}, "
-                              f"avoid links "
-                              f"{list(plan.get('avoid_links', ()))}")
-                    if plan["action"] == "exclude_and_restart" \
-                            and args.ckpt_dir:
-                        path = store.save(args.ckpt_dir, step + 1,
-                                          (params, opt_state),
-                                          extra={"data": pipe.state(),
-                                                 "loss": loss})
-                        print(f"[telemetry] mitigation checkpoint {path}")
+        with spans.span("train.step", root="step"):
+            with spans.span("train.data"):
+                tokens = jnp.asarray(next(pipe))
+            with spans.span("train.dispatch") as dispatch:
+                if cfg.enc_dec:
+                    params, opt_state, loss, gnorm = train_step(
+                        params, opt_state, tokens, enc_frames)
                 else:
-                    print(f"[telemetry] step {step}: healthy window")
-        if step % args.log_every == 0 or step == args.steps - 1:
-            print(f"step {step:5d} loss {loss:.4f} gnorm {float(gnorm):.3f}"
-                  f" {dt*1e3:.0f} ms")
-        if args.ckpt_dir and (step + 1) % args.ckpt_every == 0:
-            path = store.save(args.ckpt_dir, step + 1,
-                              (params, opt_state),
-                              extra={"data": pipe.state(),
-                                     "loss": loss})
-            print(f"[ckpt] {path}")
-    wall = time.perf_counter() - t_begin  # lint: allow-wallclock
+                    params, opt_state, loss, gnorm = train_step(
+                        params, opt_state, tokens)
+            with spans.span("train.sync") as sync:
+                loss = float(loss)
+            losses.append(loss)
+            # the telemetry signal: the step's call to its loss on the host
+            dt = sync.t1 - dispatch.t0
+            if telemetry is not None:
+                with spans.span("train.telemetry"):
+                    reported = dt
+                    if args.inject_slow_at is not None and \
+                            args.inject_slow_at <= step \
+                            < args.inject_slow_at + args.inject_slow_steps:
+                        reported *= args.inject_slow_factor
+                    verdict = telemetry.record_step(reported)
+                    if verdict is not None:
+                        _act_on(verdict, telemetry.plans[-1], step, args,
+                                (params, opt_state), pipe, loss)
+            if step % args.log_every == 0 or step == args.steps - 1:
+                with spans.span("train.log"):
+                    print(f"step {step:5d} loss {loss:.4f} gnorm "
+                          f"{float(gnorm):.3f} {dt*1e3:.0f} ms")
+            if args.ckpt_dir and (step + 1) % args.ckpt_every == 0:
+                with spans.span("train.ckpt"):
+                    path = store.save(args.ckpt_dir, step + 1,
+                                      (params, opt_state),
+                                      extra={"data": pipe.state(),
+                                             "loss": loss})
+                print(f"[ckpt] {path}")
+    wall = spans.clock() - t_begin
     if telemetry is not None:
         telemetry.flush()      # analyse any trailing partial window
         n_flagged = sum(v.flagged for v in telemetry.verdicts)
@@ -187,6 +179,28 @@ def main(argv=None):
     else:
         print(f"nothing to do (resumed at {start_step} ≥ {args.steps})")
     return losses
+
+
+def _act_on(verdict, plan: dict, step: int, args, state, pipe,
+            loss: float) -> None:
+    """Log a telemetry window's verdict; an ``exclude_and_restart`` plan
+    writes a mitigation checkpoint when ``--ckpt-dir`` is set."""
+    if not verdict.flagged:
+        print(f"[telemetry] step {step}: healthy window")
+        return
+    print(f"[telemetry] step {step}: FLAGGED {verdict.kind} "
+          f"{verdict.location} severity {verdict.severity:.1f} -> "
+          f"{plan['action']}")
+    if plan.get("exclude_cores") or plan.get("avoid_links"):
+        # registry-backed plan (remap/reroute on the pod mesh): the
+        # resource edits the restart applies
+        print(f"[telemetry] {plan['policy']} plan: exclude cores "
+              f"{list(plan.get('exclude_cores', ()))}, avoid links "
+              f"{list(plan.get('avoid_links', ()))}")
+    if plan["action"] == "exclude_and_restart" and args.ckpt_dir:
+        path = store.save(args.ckpt_dir, step + 1, state,
+                          extra={"data": pipe.state(), "loss": loss})
+        print(f"[telemetry] mitigation checkpoint {path}")
 
 
 if __name__ == "__main__":

@@ -5,27 +5,27 @@ at pod scale: uniform-batch prefill, greedy decode with the rolling KV /
 SSM cache, simple admission queue.
 
 Per-step timings are recorded in **separate** ``prefill_times`` /
-``decode_times`` series (the legacy interleaved ``step_times`` list is
-kept for compatibility): prefill steps are O(prompt·seq) and decode
-steps O(1)-ish, so mixing them in one series inflated every decode
+``decode_times`` series: prefill steps are O(prompt·seq) and decode
+steps O(1)-ish, so mixing them in one series would inflate every decode
 percentile computed downstream.  An optional ``step_hook(kind, dt)``
 callback fires after each step (``kind`` is ``'prefill'`` or
 ``'decode'``) — the live telemetry tap ``launch/serve.py --telemetry``
 uses to stream decode timings into the pod detector
 (:class:`~repro.distributed.telemetry.StepTelemetry`).  Each timing ends
 with ``block_until_ready`` on the step's next tokens, so it covers the
-device step and not only its dispatch.
+device step and not only its dispatch; it is read on
+:func:`repro.core.spans.clock`.
 """
 
 from __future__ import annotations
 
 import dataclasses
-import time
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..core.spans import clock
 from ..models import transformer as T
 
 
@@ -56,7 +56,6 @@ class ServeEngine:
         self.params = params
         self.ecfg = ecfg
         self.queue: list[Request] = []
-        self.step_times: list[float] = []      # legacy interleaved series
         self.prefill_times: list[float] = []
         self.decode_times: list[float] = []
         self.step_hook = step_hook             # fn(kind, dt) | None
@@ -77,7 +76,6 @@ class ServeEngine:
         self.queue.append(req)
 
     def _record(self, kind: str, dt: float) -> None:
-        self.step_times.append(dt)
         (self.prefill_times if kind == "prefill"
          else self.decode_times).append(dt)
         if self.step_hook is not None:
@@ -99,23 +97,23 @@ class ServeEngine:
             s = max(len(r.prompt) for r in batch)
             toks = np.stack([np.pad(r.prompt, (s - len(r.prompt), 0))
                              for r in batch]).astype(np.int32)
-            t0 = time.perf_counter()  # lint: allow-wallclock (measured)
+            t0 = clock()
             args = (self.params, toks) + ((enc_frames,) if self.cfg.enc_dec
                                           else ())
             out = self._prefill(*args)
             nxt, cache = out[0], out[1]
             memory = out[2] if self.cfg.enc_dec else None
             nxt.block_until_ready()     # time the device step, not the enqueue
-            self._record("prefill", time.perf_counter() - t0)  # lint: allow-wallclock
+            self._record("prefill", clock() - t0)
             max_new = max(r.max_new for r in batch)
             for k in range(max_new):
                 for r, t in zip(batch, np.asarray(nxt)[:, 0]):
                     if r.rid >= 0 and len(r.out_tokens) < r.max_new:
                         r.out_tokens.append(int(t))
-                t0 = time.perf_counter()  # lint: allow-wallclock
+                t0 = clock()
                 nxt, cache = self._decode(self.params, nxt, cache,
                                           jnp.int32(s + k), memory)
                 nxt.block_until_ready()
-                self._record("decode", time.perf_counter() - t0)  # lint: allow-wallclock
+                self._record("decode", clock() - t0)
             done.extend(r for r in batch if r.rid >= 0)
         return done

@@ -307,14 +307,20 @@ def test_kernel_audit_catches_grid_rank_drift():
 
 
 def test_lint_wallclock_allowlist_is_tight():
-    """campaign.py keeps exactly one blessed wall-clock reader."""
-    src = (REPO / "src/repro/core/campaign.py").read_text()
+    """``spans.clock`` is the package's one blessed wall-clock reader:
+    no other file in the lint's scope carries the marker."""
+    src = (REPO / "src/repro/core/spans.py").read_text()
     assert src.count("time.perf_counter()") == 1
-    assert "# lint: allow-wallclock" in src
+    assert src.count("# lint: allow-wallclock") == 1
     # stripping the marker re-triggers the lint
     stripped = src.replace("# lint: allow-wallclock", "")
-    fs = lints.lint_source(stripped, "<campaign>")
+    fs = lints.lint_source(stripped, "<spans>")
     assert any(f.rule == "wallclock" for f in fs)
+    marked = sorted(
+        str(f.relative_to(REPO)) for scope in lints.WALLCLOCK_SCOPE
+        for f in (REPO / "src/repro" / scope).rglob("*.py")
+        if "# lint: allow-wallclock" in f.read_text())
+    assert marked == ["src/repro/core/spans.py"]
 
 
 # ---------------------------------------------------------------------------

@@ -16,6 +16,7 @@ described chip cannot be read back here.
 """
 
 import os
+import re
 from functools import partial
 
 import jax
@@ -68,6 +69,13 @@ def _assert_kernel(compiled):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+def _assert_scoped_loop(compiled, scope):
+    """The program's loop carries ``scope`` in its op name, which is how
+    a profiler trace's device op is found by name."""
+    assert re.search(r'= .*\bwhile\(.*op_name="[^"]*/' + re.escape(scope)
+                     + '/while"', compiled.as_text())
+
+
 def test_batched_recorder_compiles(shape):
     from repro.kernels.sketch_update import batched, ref
     n = 4096
@@ -79,6 +87,7 @@ def test_batched_recorder_compiles(shape):
         state, drain, i32, i32, i32, f32, f32, f32, f32,
         H=POD_SKETCH.H).compile()
     assert compiled.memory_analysis() is not None
+    _assert_scoped_loop(compiled, "sketch.scan")
 
 
 def test_failrank_loop_compiles(shape):
@@ -90,6 +99,7 @@ def test_failrank_loop_compiles(shape):
         shape((n_edges,), jnp.int32), shape((n_edges,), jnp.int32),
         p.lam, p.alpha, p.beta, p.gamma, p.eps, p.max_iters).compile()
     assert compiled.memory_analysis() is not None
+    _assert_scoped_loop(compiled, "failrank.iterate")
 
 
 def test_failrank_step_kernel_compiles(shape):
