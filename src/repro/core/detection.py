@@ -166,34 +166,48 @@ class LinkInference:
 
 
 @spans.traced("sloth.detect.links.em")
-def em_link_inverse_bw(A: np.ndarray, T: np.ndarray, V: np.ndarray,
-                       weights: np.ndarray, hop_latency: float = 0.0,
+def em_link_inverse_bw(rows: np.ndarray, cols: np.ndarray, n_links: int,
+                       T: np.ndarray, V: np.ndarray, weights: np.ndarray,
+                       hop_latency: float = 0.0,
                        iters: int = 60) -> np.ndarray:
-    """EM for  T_e ≈ Σ_l A_el · V_e · θ_l.
+    """EM for  T_e ≈ Σ_l A_el · V_e · θ_l,  with A given by its nonzeros.
+
+    ``(rows, cols)`` are the route lists of ``Topology.path_links``: A_el = 1
+    exactly where some entry is (e, l).  Every product with A is a
+    ``bincount`` over those entries, so the cost follows the route lengths,
+    not events x links.
 
     E-step: split each observed delay over its links proportionally to the
     current θ; M-step: re-estimate θ_l from its expected delay share.
     Multiplicative updates keep θ ≥ 0 (bandwidths are positive).
     """
-    n_e, n_l = A.shape
-    T = np.maximum(T - hop_latency * A.sum(axis=1), 1e-12)
-    traffic = (A * (weights * V)[:, None]).sum(axis=0)        # Σ_e w V A
+    spans.count("path_links", len(rows))
+    n_e = len(T)
+
+    def per_event(x_links):            # Σ_l A_el x_l
+        return np.bincount(rows, weights=x_links[cols], minlength=n_e)
+
+    def per_link(x_events):            # Σ_e A_el x_e
+        return np.bincount(cols, weights=x_events[rows], minlength=n_links)
+
+    hops = np.bincount(rows, minlength=n_e).astype(np.float64)
+    T = np.maximum(T - hop_latency * hops, 1e-12)
+    traffic = per_link(weights * V)                           # Σ_e w V A
     seen = traffic > 0
-    theta0 = float((T / np.maximum((A * V[:, None]).sum(axis=1),
-                                   1e-12)).mean())
-    theta = np.full(n_l, theta0)
+    theta0 = float((T / np.maximum(hops * V, 1e-12)).mean())
+    theta = np.full(n_links, theta0)
     for _ in range(iters):
-        pred = (A * V[:, None]) @ theta                        # T̂_e
+        pred = V * per_event(theta)                            # T̂_e
         ratio = T / np.maximum(pred, 1e-300)
         # expected delay on link l: Σ_e w_e · A_el V_e θ_l · ratio_e
-        num = theta * ((A * (weights * V * ratio)[:, None]).sum(axis=0))
+        num = theta * per_link(weights * V * ratio)
         theta_new = num / np.maximum(traffic, 1e-300)
         theta = np.where(seen, theta_new, theta)
     # shrink poorly-observed links toward the global estimate: a link seen by
     # <~3 events has an essentially unidentified θ in the underdetermined
     # system, and the raw EM value is an artefact of the initialisation.
     if seen.any():
-        n_events = (A > 0).sum(axis=0)
+        n_events = np.bincount(cols, minlength=n_links)
         lam = n_events / (n_events + 3.0)
         shrunk = lam * theta + (1 - lam) * np.median(theta[seen])
         theta = np.where(seen, shrunk, theta)
@@ -240,21 +254,25 @@ def detect_links(patterns: list[Pattern], mesh: Topology, total_time: float,
     if len(inter) == 0:
         return LinkInference(theta, observed, cands)
     pairs = [(int(src[i]), int(dst[i])) for i in inter]
-    A = mesh.path_matrix(pairs)                     # [events, links]
-    spans.count("events", A.shape[0])
-    T = np.maximum(min_T[inter] - hop_latency * A.sum(axis=1), 1e-12)
+    rows, cols = mesh.path_links(pairs)
+    n_e = len(pairs)
+    spans.count("events", n_e)
+    hops = np.bincount(rows, minlength=n_e)
+    T = np.maximum(min_T[inter] - hop_latency * hops, 1e-12)
     V = mean_V[inter]
     W = cnt[inter]
     win = windows[inter]
 
-    theta_bar = em_link_inverse_bw(A, min_T[inter], V, W, hop_latency,
-                                   em_iters)
-    seen_any = A.sum(axis=0) > 0
+    theta_bar = em_link_inverse_bw(rows, cols, n_l, min_T[inter], V, W,
+                                   hop_latency, em_iters)
+    seen_any = np.bincount(cols, minlength=n_l) > 0
     if seen_any.any():
         # floor θ̄: the multiplicative EM can drive rarely-blamed links to 0,
         # which would make their events' predicted time vanish
         theta_bar = np.maximum(theta_bar,
                                0.05 * np.median(theta_bar[seen_any]))
+    A = np.zeros((n_e, n_l))                        # [events, links]
+    A[rows, cols] = 1.0
     pred = (A * V[:, None]) @ theta_bar             # T̂_e
     ratio_e = np.clip(T / np.maximum(pred, 1e-300), 0.0, 50.0)
     share = (A * (V[:, None] * theta_bar[None, :])) \

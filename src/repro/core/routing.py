@@ -3,11 +3,11 @@
 The fabric model is a first-class :class:`Topology`: core/link identity,
 deterministic routing (``route`` / ``route_avoiding``), incidence queries
 (``links_of_router`` / ``neighbours``), geometric distance (``hops``), the
-EM path matrix (``path_matrix``) and a per-core ``rate_class`` vector of
-baseline-capacity multipliers (all-ones on homogeneous fabrics).  Link
-indexing is shared by the simulator, the link-level EM detector and the MCG
-builder, so a physical link has one identity everywhere.  Links are
-directed: ``(u, v)`` with u, v core ids.
+EM's route lists (``path_links``) and path matrix (``path_matrix``), and a
+per-core ``rate_class`` vector of baseline-capacity multipliers (all-ones
+on homogeneous fabrics).  Link indexing is shared by the simulator, the
+link-level EM detector and the MCG builder, so a physical link has one
+identity everywhere.  Links are directed: ``(u, v)`` with u, v core ids.
 
 Built-in fabrics (registered under the same string-keyed registry idiom as
 ``core/detectors.py`` and ``mitigate/policy.py``):
@@ -58,7 +58,7 @@ class Topology:
     degenerate 1- or 2-wide wrap fabrics stay well-formed) and by
     implementing ``route`` and ``hops``.  Everything else — link-id
     bijection, precomputed router incidence, deterministic
-    ``route_avoiding`` BFS, ``path_matrix`` — is shared.
+    ``route_avoiding`` BFS, ``path_links``, ``path_matrix`` — is shared.
     """
 
     def __init__(self, width: int, height: int | None = None):
@@ -160,12 +160,23 @@ class Topology:
         path.reverse()
         return path
 
+    def path_links(self, pairs: list[tuple[int, int]]
+                   ) -> tuple[np.ndarray, np.ndarray]:
+        """Route lists ``(rows, cols)``: one int64 entry per distinct link
+        on each event's route, in event order and then link-id order —
+        the nonzeros of :meth:`path_matrix`, without the dense matrix."""
+        routes = [sorted(set(self.route(s, d))) for s, d in pairs]
+        rows = np.repeat(np.arange(len(routes), dtype=np.int64),
+                         [len(r) for r in routes])
+        cols = np.fromiter((lid for r in routes for lid in r),
+                           dtype=np.int64, count=len(rows))
+        return rows, cols
+
     def path_matrix(self, pairs: list[tuple[int, int]]) -> np.ndarray:
         """A[e, l] = 1 if event e's route traverses link l (EM's A matrix)."""
+        rows, cols = self.path_links(pairs)
         A = np.zeros((len(pairs), self.n_links), dtype=np.float64)
-        for i, (s, d) in enumerate(pairs):
-            for lid in self.route(s, d):
-                A[i, lid] = 1.0
+        A[rows, cols] = 1.0
         return A
 
 
@@ -354,12 +365,10 @@ class DetourTopology:
             self._route_cache[key] = path
         return path
 
-    def path_matrix(self, pairs: list[tuple[int, int]]) -> np.ndarray:
-        A = np.zeros((len(pairs), self.base.n_links), dtype=np.float64)
-        for i, (s, d) in enumerate(pairs):
-            for lid in self.route(s, d):
-                A[i, lid] = 1.0
-        return A
+    # Not inherited through __getattr__: the base's methods would call the
+    # base's route and ignore the detour.  These bind this class's route.
+    path_links = Topology.path_links
+    path_matrix = Topology.path_matrix
 
 
 # back-compat spelling: the historical mesh-only detour wrapper

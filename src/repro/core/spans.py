@@ -17,9 +17,9 @@ in the profiler's trace:
   every span lands on the trace's host plane, on the device events'
   clock, so a device idle gap can be put down to the span that covers it.
 * :func:`count` adds to the innermost open span's counters.  Work sizes
-  (runs scanned, patterns decoded, path events solved, FailRank
-  iterations) are counted at span boundaries; no span is opened inside a
-  per-item loop.
+  (runs scanned, patterns decoded, path events solved, route entries the
+  EM iterates over, FailRank iterations) are counted at span boundaries;
+  no span is opened inside a per-item loop.
 * Closed spans go to an in-memory ring per name, ``RING_SIZE`` entries
   each; a ring that overflows drops its oldest entries and counts them.
   :func:`within` returns ``None`` rather than a short list when entries
@@ -27,7 +27,8 @@ in the profiler's trace:
 
 An operator reads both in process: ``within("sloth.detect.links.em", t0,
 t1)`` lists the EM's spans started in ``[t0, t1]`` on :func:`clock`, each
-with ``.seconds``; ``within("sloth.detect.links")`` gives the same for link
+with ``.seconds`` and ``.counts["path_links"]``, the route entries it
+iterated over; ``within("sloth.detect.links")`` gives the same for link
 detection, and each span's ``.counts["events"]`` is the number of path
 events its EM solved.  Spans of one verdict share ``ids["verdict"]``.
 

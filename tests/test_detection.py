@@ -5,7 +5,7 @@ import pytest
 from proptest import given, settings, st
 
 from repro.core.detection import em_link_inverse_bw, gamma_sf
-from repro.core.routing import Mesh2D
+from repro.core.routing import DetourTopology, Mesh2D, Torus2D
 
 pytestmark = pytest.mark.filterwarnings("ignore::DeprecationWarning")
 
@@ -51,15 +51,66 @@ def test_em_recovers_slow_link():
              for _ in range(400)]
     pairs = [p for p in pairs if p[0] != p[1]]
     A = mesh.path_matrix(pairs)
+    rows, cols = mesh.path_links(pairs)
     V = rng.uniform(1e3, 1e5, len(pairs))
     T = (A * V[:, None]) @ theta_true
     T *= rng.gamma(64, 1 / 64, len(T))       # mild noise
-    th = em_link_inverse_bw(A, T, V, np.ones(len(T)))
+    th = em_link_inverse_bw(rows, cols, mesh.n_links, T, V, np.ones(len(T)))
     seen = A.sum(axis=0) > 0
     assert seen[slow]
     ranked = np.argsort(-np.where(seen, th, 0))
     assert ranked[0] == slow
     assert th[slow] > 4 * np.median(th[seen])
+
+
+def _dense_em(A, T, V, w, hop_latency=0.0, iters=60):
+    """The EM as a product with the dense events x links matrix: the
+    oracle for the route-list form."""
+    T = np.maximum(T - hop_latency * A.sum(axis=1), 1e-12)
+    traffic = (A * (w * V)[:, None]).sum(axis=0)
+    seen = traffic > 0
+    theta = np.full(A.shape[1], float(
+        (T / np.maximum((A * V[:, None]).sum(axis=1), 1e-12)).mean()))
+    for _ in range(iters):
+        ratio = T / np.maximum((A * V[:, None]) @ theta, 1e-300)
+        num = theta * (A * (w * V * ratio)[:, None]).sum(axis=0)
+        theta = np.where(seen, num / np.maximum(traffic, 1e-300), theta)
+    if seen.any():
+        n = (A > 0).sum(axis=0)
+        lam = n / (n + 3.0)
+        theta = np.where(seen, lam * theta
+                         + (1 - lam) * np.median(theta[seen]), theta)
+    return theta
+
+
+def _detour16():
+    base = Mesh2D(16)
+    return DetourTopology(base, base.route(0, 255)[:3])
+
+
+@pytest.mark.parametrize("make,hop_latency", [
+    (lambda: Mesh2D(16), 0.0),
+    (lambda: Mesh2D(16), 50e-9),
+    (lambda: Torus2D(8, 6), 50e-9),
+    (_detour16, 50e-9),
+], ids=["mesh16-hop0", "mesh16-hop50ns", "torus8x6", "detour16"])
+def test_em_over_route_lists_matches_dense(make, hop_latency):
+    topo = make()
+    rng = np.random.default_rng(7)
+    ends = rng.integers(0, topo.n_cores, (2000, 2))
+    pairs = [(int(s), int(d)) for s, d in ends if s != d]
+    A = topo.path_matrix(pairs)
+    theta_true = rng.gamma(4.0, 0.25e-9, topo.n_links)
+    theta_true[rng.integers(topo.n_links)] *= 10
+    V = rng.uniform(1e3, 1e5, len(pairs))
+    T = V * (A @ theta_true) * rng.gamma(64, 1 / 64, len(pairs)) \
+        + hop_latency * A.sum(axis=1)
+    w = rng.integers(1, 50, len(pairs)).astype(np.float64)
+    rows, cols = topo.path_links(pairs)
+    got = em_link_inverse_bw(rows, cols, topo.n_links, T, V, w, hop_latency)
+    want = _dense_em(A, T, V, w, hop_latency)
+    assert got.shape == want.shape
+    assert np.max(np.abs(got - want) / np.abs(want)) <= 1e-12
 
 
 def test_gamma_sf_properties():

@@ -163,7 +163,8 @@ def test_pod_verdict_span_tree(fresh, impl):
     assert links.counts == {"events": links.counts["events"]}
     assert links.counts["events"] > 0
     em, = kids(links, "sloth.detect.links.em")
-    assert em.counts == {}
+    assert em.counts == {"path_links": em.counts["path_links"]}
+    assert em.counts["path_links"] >= links.counts["events"]
     assert links.t0 <= em.t0 <= em.t1 <= links.t1
     fr, = kids(last, "sloth.failrank")
     for name in ("sloth.failrank.mcg", "sloth.failrank.iterate",
@@ -178,6 +179,30 @@ def test_pod_verdict_span_tree(fresh, impl):
         for s in spans.within(name, t0):
             root = next(r for r in roots if r.ids == s.ids)
             assert root.t0 <= s.t0 <= s.t1 <= root.t1
+
+
+def test_link_em_counts_its_route_entries(fresh):
+    from repro.core.detection import detect_links
+    from repro.core.routing import Mesh2D
+    from repro.core.sketch import Pattern
+
+    mesh = Mesh2D(4)
+    rng = np.random.default_rng(1)
+    ends = [(int(s), int(d)) for s, d in rng.integers(0, 16, (300, 2))]
+    pats = [Pattern(key=s | d << 12, count=4, sum_dur=4e-6, sum_sq_dur=4e-12,
+                    sum_val=4e5, t_first=i * 1e-6, t_last=i * 1e-6 + 5e-7,
+                    arrival=i, min_dur=1e-6 * (1 + mesh.hops(s, d)))
+            for i, (s, d) in enumerate(ends)]
+    t0 = spans.clock()
+    detect_links(pats, mesh, total_time=len(pats) * 1e-6)
+    links, = spans.within("sloth.detect.links", t0)
+    em, = spans.within("sloth.detect.links.em", t0)
+    assert em.parent == links.seq
+    inter = [e for e in ends if e[0] != e[1]]
+    assert links.counts == {"events": len(inter)}
+    rows, _ = mesh.path_links(inter)
+    assert em.counts == {"path_links": len(rows)}
+    assert len(rows) == sum(mesh.hops(s, d) for s, d in inter)
 
 
 def test_telemetry_flush_spans(fresh):

@@ -147,6 +147,31 @@ def test_route_avoiding_deterministic_and_honoured(name, topo):
         assert cur == dst
 
 
+def _detour_over_a_base_route():
+    base = Torus2D(4, 4)
+    return DetourTopology(base, base.route(0, 10)[:1])
+
+
+@pytest.mark.parametrize("name,topo",
+                         FABRICS + [("detour", _detour_over_a_base_route())],
+                         ids=lambda v: v if isinstance(v, str)
+                         else f"{v.width}x{v.height}")
+def test_path_links_are_the_nonzeros_of_path_matrix(name, topo):
+    pairs = [(s, d) for s in range(topo.n_cores)
+             for d in range(topo.n_cores)]
+    rows, cols = topo.path_links(pairs)
+    assert rows.dtype == cols.dtype == np.int64
+    want_rows, want_cols = np.nonzero(topo.path_matrix(pairs))
+    np.testing.assert_array_equal(rows, want_rows)
+    np.testing.assert_array_equal(cols, want_cols)
+    assert len(set(zip(rows.tolist(), cols.tolist()))) == len(rows)
+    # the routes it lists are this fabric's own, detours included
+    i = pairs.index((0, 10))
+    assert cols[rows == i].tolist() == sorted(set(topo.route(0, 10)))
+    if name == "detour":
+        assert not set(cols[rows == i].tolist()) & topo.avoid
+
+
 def test_torus_wrap_distances():
     t = Torus2D(6, 6)
     # edge-to-edge neighbours are one wrap hop apart
