@@ -35,7 +35,7 @@ def main(argv=None) -> int:
     import jax
     jax.config.update("jax_compilation_cache_dir", cache)
     H.require_chips(cell.chips)
-    driver = H.driver_for(cell, BENCH_DIR)
+    driver = H.driver_for(cell)
     worst: dict = {}
     for seed in args.seeds:
         r = driver.calibrate(cell, seed)

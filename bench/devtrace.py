@@ -7,11 +7,18 @@ The trace (``<dir>/plugins/profile/<time>/*.xplane.pb``) is read with
 that the harness puts around the measured window; host spans
 ``bench.<name>`` say what the host was doing in each idle gap.
 
-* busy: the union of the operation intervals inside the window, averaged
-  over the device planes;
-* idle share: 1 - busy / window;
+* covered window: from the window's opening to the end of the last
+  operation that any device plane kept inside it.  The profiler keeps a
+  bounded number of device events and drops the rest, so a long window's
+  trace can stop part-way; past its last kept operation it says nothing,
+  and counting that time as idle would over-read the idle share.  Where
+  nothing was dropped, this leaves out only the host's tail after the
+  window's last device operation;
+* busy: the union of the operation intervals inside the covered window,
+  averaged over the device planes;
+* idle share: 1 - busy / covered window;
 * device_ops: seconds per operation name, largest first;
-* idle_gaps: the device's idle seconds inside the window, by the
+* idle_gaps: the device's idle seconds inside the covered window, by the
   innermost host span that covers each gap's middle (``host`` where none
   does), largest first.
 """
@@ -102,9 +109,11 @@ def summarize(device_ops: list[list[tuple[str, float, float]]],
 
     ``device_ops`` holds one list per device of ``(name, start, end)``;
     ``host_spans`` the host's ``(name, start, end)``; ``window`` the
-    traced window's bounds.
+    traced window's bounds.  ``window_s`` is the covered window's length.
     """
     lo, hi = window
+    hi = max((min(e, hi) for ops in device_ops for _, s, e in ops
+              if e > lo and s < hi), default=hi)
     window_s = hi - lo
     busy_per_dev, by_op, idle = [], defaultdict(float), defaultdict(float)
     for ops in device_ops:

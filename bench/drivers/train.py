@@ -6,8 +6,8 @@ the traffic asks for it).  The harness does not copy its loop.  It wraps
 three attributes around it:
 
 * ``repro.models.transformer.init_model`` returns the benchmark's weights,
-  made from the seed on the device in one jitted call
-  (``bench/reference/lm.py``), in the layout the program's own init gives;
+  made from the seed on the device in one jitted call by the cell's
+  reference, in the layout the program's own init gives;
 * ``TokenPipeline.__next__`` hands out the benchmark's tokens from the
   seed and takes each step's start time; ``setup_steps`` steps in, it
   opens the window, and at the first step start past the window's end it
@@ -26,6 +26,28 @@ the reference, by leaf slice, and, with telemetry, a sample of the
 telemetry verdicts drawn from the seed with the reference pipeline over
 the same windows, layer by layer (recorder patterns, core scores, link
 estimates, FailRank where reached, the verdict).
+
+The configuration names its plain reference with ``"reference":
+"<name>"``, ``bench/reference/<name>.py`` (``Cell.reference``); the
+driver and ``train_mfu`` reach the model only through it, so a model
+joins by new files.  A training reference provides:
+
+* ``_Frozen(model)``, a hashable view of the configuration's ``model``
+  group, and ``init_params(frozen, key)``, the seed's weights in the
+  program's layout, traceable under ``jax.jit``;
+* ``make_params_from_seed(model, seed)``, the same weights made on the
+  device in one jitted call;
+* ``leaf_norms(tree)``, the norm of every leaf slice in leaf order;
+* ``batch(seed, step, rows, seq, vocab)``, the tokens of one step;
+* ``run_reference(model, opt, seed, rows, seq, steps=3, block=2,
+  dtype=float32, use_rows=None)``, the losses, the first clipped
+  gradient's and the three steps' change's norms by leaf slice;
+* ``train_flops_per_step(model, batch, seq)``, the model FLOPs of one
+  step; without it ``train_mfu`` is left out.
+
+The Llama reference, ``"reference": "lm"``, provides all of them; its
+generic parts (the token stream, AdamW, ``leaf_norms``, ``_Frozen``) are
+there for a later reference to import, not to copy.
 """
 
 from __future__ import annotations
@@ -45,7 +67,6 @@ sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
 
 from harness import (BENCH_DIR, Outcome, Reservoir, load_module,  # noqa: E402
                      percentile)
-from reference import lm  # noqa: E402
 from reference import sloth as pod_ref  # noqa: E402
 
 pod = load_module(BENCH_DIR / "drivers" / "pod.py", "bench_pod_compare")
@@ -68,19 +89,19 @@ def argv_for(config: dict, traffic: dict, seed: int) -> list[str]:
     return argv
 
 
-@jax.jit
-def _first_grad_norms(first_moment, b1):
+@functools.partial(jax.jit, static_argnums=0)
+def _first_grad_norms(ref, first_moment, b1):
     """The first (clipped) gradient by leaf slice, from AdamW's first
     moment after one step."""
-    return lm.leaf_norms(first_moment) / (1 - b1)
+    return ref.leaf_norms(first_moment) / (1 - b1)
 
 
-@functools.partial(jax.jit, static_argnums=0)
-def _change_norms(frozen, params, key):
+@functools.partial(jax.jit, static_argnums=(0, 1))
+def _change_norms(ref, frozen, params, key):
     """The weights' change since the seed's weights, by leaf slice.  The
     key is an argument, so every seed runs the same compiled program."""
-    start = lm.init_params(frozen, key)
-    return lm.leaf_norms(jax.tree.map(lambda a, b: a - b, params, start))
+    start = ref.init_params(frozen, key)
+    return ref.leaf_norms(jax.tree.map(lambda a, b: a - b, params, start))
 
 
 class StepProbe:
@@ -88,25 +109,26 @@ class StepProbe:
     before call 1 the first (clipped) gradient from AdamW's first moment,
     before call 3 the weights' change since the seed's weights."""
 
-    def __init__(self, fn, model: dict, seed: int, b1: float, rec):
+    def __init__(self, fn, ref, model: dict, seed: int, b1: float, rec):
         self.fn = fn
+        self.ref = ref
         self.rec = rec
         self.calls = 0
         self.losses = []
         self.grad_norms = self.delta_norms = None
         self.b1 = b1
         self.key = jax.random.PRNGKey(seed)
-        self.frozen = lm._Frozen(model)
+        self.frozen = ref._Frozen(model)
 
     def __call__(self, params, opt_state, tokens, *rest):
         i = self.calls
         self.calls += 1
         if i == 1:
-            self.grad_norms = np.asarray(_first_grad_norms(opt_state["m"],
-                                                           self.b1))
+            self.grad_norms = np.asarray(_first_grad_norms(
+                self.ref, opt_state["m"], self.b1))
         if i == 3:
-            self.delta_norms = np.asarray(_change_norms(self.frozen, params,
-                                                        self.key))
+            self.delta_norms = np.asarray(_change_norms(
+                self.ref, self.frozen, params, self.key))
         with self.rec.span("train_step"):
             out = self.fn(params, opt_state, tokens, *rest)
         self.losses.append(out[2])
@@ -140,6 +162,7 @@ class Loop:
 
     def __init__(self, ctx, setup_steps: int):
         self.ctx = ctx
+        self.ref = ctx.cell.reference
         self.setup_steps = setup_steps
         self.starts: list[float] = []
         self.step = 0
@@ -159,7 +182,8 @@ class Loop:
         if self.t_open is not None:
             self.starts.append(now)
         c = pipe.cfg
-        out = lm.batch(self.ctx.seed, self.step, c.batch, c.seq, c.vocab)
+        out = self.ref.batch(self.ctx.seed, self.step, c.batch, c.seq,
+                             c.vocab)
         self.step += 1
         return out
 
@@ -173,11 +197,12 @@ def run(ctx) -> Outcome:
     cfg, tr, rec = ctx.config, ctx.traffic, ctx.rec
     model, opt = cfg["model"], cfg["train"]["optimizer"]
     loop = Loop(ctx, tr["setup_steps"])
+    ref = loop.ref
     probe = {}
 
     def init_model(arch_cfg, rng, dtype=None):
         want = jax.eval_shape(lambda: orig_init(arch_cfg, rng, dtype=dtype))
-        got = lm.make_params_from_seed(model, ctx.seed)
+        got = ref.make_params_from_seed(model, ctx.seed)
         if jax.tree.structure(want) != jax.tree.structure(got) or any(
                 a.shape != b.shape for a, b in zip(jax.tree.leaves(want),
                                                    jax.tree.leaves(got))):
@@ -186,7 +211,7 @@ def run(ctx) -> Outcome:
         return got
 
     def jit(fn, **kw):
-        probe["step"] = StepProbe(jax.jit(fn, **kw), model, ctx.seed,
+        probe["step"] = StepProbe(jax.jit(fn, **kw), ref, model, ctx.seed,
                                   opt["b1"], rec)
         return probe["step"]
 
@@ -224,7 +249,7 @@ def run(ctx) -> Outcome:
     del step, probe
     gc.collect()
 
-    want = lm.run_reference(model, opt, ctx.seed, tr["batch"], tr["seq"])
+    want = ref.run_reference(model, opt, ctx.seed, tr["batch"], tr["seq"])
     nums = compare_steps(first, grad_norms, delta_norms, want)
     if tr["telemetry"]:
         nums.update(seen.numbers())
@@ -383,7 +408,7 @@ def calibrate(cell, seed: int) -> dict:
     want = out.extra["reference"]
     for side, kw in (("control", {"dtype": jnp.bfloat16}),
                      ("half_batch", {"use_rows": rows // 2})):
-        r = lm.run_reference(model, opt, seed, rows, seq, **kw)
+        r = cell.reference.run_reference(model, opt, seed, rows, seq, **kw)
         readings[side] = compare_steps(r["losses"], r["grad_norms"],
                                        r["delta_norms"], want)
     return readings

@@ -10,12 +10,18 @@ per-layer metric lives in a file of its own and is found by the name that
   that drives that kind of system.
 * ``bench/traffic/<traffic>.json``: the parameters of one traffic mix,
   read by the driver's generator.
+* ``bench/reference/<reference>.py``: the plain reference that a
+  configuration names with its ``reference`` key, where its driver asks
+  for one (``Cell.reference``).
 * ``bench/layers/<metric>.py``: a reader with ``read(run) -> float |
   None`` that takes one per-layer metric from the run's spans, counters
   and trace.  ``None`` leaves the metric out of the result line.
 * ``bench/limits/<cell>.json``: the limit of every number that the cell's
   correctness check compares (``limits``), beside the readings each was
   set from (``readings``).
+
+A cell remembers the directory it was resolved from (``Cell.bench_dir``);
+its driver, readers and reference are loaded from there alone.
 """
 
 from __future__ import annotations
@@ -61,6 +67,18 @@ class Cell:
     end_to_end: list[dict]      # the end-to-end metrics this cell reports
     per_layer: list[dict]       # the per-layer metrics read in this cell
     limits: dict[str, float]    # the limit of each number compared
+    bench_dir: Path             # where its driver, readers and reference are
+
+    @functools.cached_property
+    def reference(self):
+        """The plain reference that the configuration names
+        (``"reference": "<name>"``), ``bench/reference/<name>.py``,
+        imported once per cell."""
+        name = self.config.get("reference")
+        if not name:
+            raise BenchError(f"{self.bench_dir / 'configs'}/"
+                             f"{self.config_name}.json names no reference")
+        return load_module(self.bench_dir / "reference" / f"{name}.py")
 
     def check(self, name: str, value: float) -> "Check":
         """``value`` of the number ``name`` beside the cell's limit."""
@@ -96,7 +114,8 @@ def resolve_cell(name: str, bench_dir: Path = BENCH_DIR,
                                   / f"{w['traffic']}.json"),
                 end_to_end=e2e, per_layer=per_layer,
                 limits=read_json(bench_dir / "limits"
-                                 / f"{name}.json")["limits"])
+                                 / f"{name}.json")["limits"],
+                bench_dir=bench_dir)
 
 
 def read_json(path: Path) -> dict:
@@ -117,12 +136,13 @@ def load_module(path: Path, name: str | None = None):
     return mod
 
 
-def driver_for(cell: Cell, bench_dir: Path = BENCH_DIR):
-    return load_module(bench_dir / "drivers" / f"{cell.config['driver']}.py")
+def driver_for(cell: Cell):
+    return load_module(cell.bench_dir / "drivers"
+                       / f"{cell.config['driver']}.py")
 
 
-def reader_for(metric: str, bench_dir: Path = BENCH_DIR):
-    return load_module(bench_dir / "layers" / f"{metric}.py")
+def reader_for(cell: Cell, metric: str):
+    return load_module(cell.bench_dir / "layers" / f"{metric}.py")
 
 
 # ---------------------------------------------------------------------------
@@ -384,8 +404,7 @@ class Run:
         return self.outcome.extra.get("counters", {}).get(name)
 
 
-def metric_values(cell: Cell, ctx: Context, outcome: Outcome,
-                  bench_dir: Path = BENCH_DIR) -> dict:
+def metric_values(cell: Cell, ctx: Context, outcome: Outcome) -> dict:
     if not ctx.trace:
         out = {}
         for m in cell.end_to_end:
@@ -397,7 +416,7 @@ def metric_values(cell: Cell, ctx: Context, outcome: Outcome,
     run = Run(cell, ctx.rec, outcome, ctx.trace_summary, ctx.peak)
     out = {}
     for m in cell.per_layer:
-        v = reader_for(m["name"], bench_dir).read(run)
+        v = reader_for(cell, m["name"]).read(run)
         if v is not None:
             out[m["name"]] = {"value": v, "unit": m["unit"]}
     return out

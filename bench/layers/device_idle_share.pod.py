@@ -1,5 +1,6 @@
 """The device's idle share of the traced window, in percent: 1 minus the
-union of the device's operation intervals over the window's length
+union of the device's operation intervals over the length of the window
+that the trace covers, up to the last operation it kept
 (``bench/devtrace.py``)."""
 
 

@@ -1,6 +1,6 @@
 """Seconds of the link EM per verdict: the program's
-``sloth.detect.links.em`` span (the dense events x links solve), over the
-``pod.verdict`` spans in the window."""
+``sloth.detect.links.em`` span (the solve that iterates over each path
+event's route links), over the ``pod.verdict`` spans in the window."""
 
 import program_spans
 

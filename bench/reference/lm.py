@@ -19,6 +19,10 @@ projections), norms one.
 ``dtype=jnp.bfloat16`` runs the same steps with the weights, the
 activations and AdamW's state in bfloat16: the control that the
 comparison must fail.
+
+It provides the whole interface of a training reference
+(``bench/drivers/train.py``); its model FLOPs per step are
+``bench/flops.py``'s, which counts this architecture.
 """
 
 from __future__ import annotations
@@ -28,6 +32,7 @@ import math
 import jax
 import jax.numpy as jnp
 import numpy as np
+from flops import train_flops_per_step  # noqa: F401  (the interface's)
 
 # ---------------------------------------------------------------------------
 # the seed's weights and tokens

@@ -67,7 +67,7 @@ def main(argv=None) -> int:
         jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
         devices = H.require_chips(cell.chips)
         peak = H.load_peak(devices[0].device_kind, BENCH_DIR)
-        driver = H.driver_for(cell, BENCH_DIR)
+        driver = H.driver_for(cell)
         out_dir = ROOT / ".bench_out" / cell.name
         ctx = H.Context(cell, args.seed, args.seconds, bool(args.trace),
                         out_dir, peak, T_START)
@@ -77,7 +77,7 @@ def main(argv=None) -> int:
         with contextlib.redirect_stdout(sys.stderr):
             outcome = driver.run(ctx)
             ctx.reduce_trace()
-            metrics = H.metric_values(cell, ctx, outcome, BENCH_DIR)
+            metrics = H.metric_values(cell, ctx, outcome)
     except H.BenchError as e:
         print(f"bench: {e}", file=sys.stderr)
         return 2

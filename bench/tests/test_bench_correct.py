@@ -80,7 +80,8 @@ def failed_numbers(nums: dict, limits: dict) -> list[str]:
 # the control fails
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("name", ["pod16-slowchip", "pod16-healthy"])
+@pytest.mark.parametrize("name", ["pod16-slowchip", "pod16-healthy",
+                                  "pod16-aged"])
 def test_pod_control_fails_and_program_passes(name):
     cell = small_cell(name)
     r = H.driver_for(cell).calibrate(cell, 31337)
@@ -117,7 +118,7 @@ def test_telemetry_control_fails_and_program_passes():
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("name", ["pod16-slowchip", "pod16-healthy",
-                                  "smollm135m-train-plain",
+                                  "pod16-aged", "smollm135m-train-plain",
                                   "smollm135m-train-monitored"])
 def test_a_sound_run_is_correct(name):
     line = run_cell(small_cell(name))
@@ -152,7 +153,8 @@ def _pod_fault(monkeypatch, fault: str):
         monkeypatch.setattr(tel.PodDetector, "observe", altered)
 
 
-@pytest.mark.parametrize("name", ["pod16-slowchip", "pod16-healthy"])
+@pytest.mark.parametrize("name", ["pod16-slowchip", "pod16-healthy",
+                                  "pod16-aged"])
 @pytest.mark.parametrize("fault", ["state_unchanged", "half_batch",
                                    "answer_altered"])
 def test_a_broken_pod_run_is_not_correct(monkeypatch, name, fault):

@@ -223,11 +223,11 @@ def test_new_files_are_found_by_name(tmp_path):
     assert cell.limits["theta_gap"] > 0
     assert cell.check("theta_gap", 0.0).ok
     assert "verdicts.pod" in [m["name"] for m in cell.per_layer]
-    assert H.driver_for(cell, bench_dir).__name__ == "bench_pod"
+    assert H.driver_for(cell).__name__ == "bench_pod"
     out = _outcome()
     out.extra["counters"] = {"verdicts": 4}
     run = H.Run(cell, H.Recorder(), out, None, None)
-    assert H.reader_for("verdicts.pod", bench_dir).read(run) == 4
+    assert H.reader_for(cell, "verdicts.pod").read(run) == 4
     # the metric without a workloads list reaches every cell that reports
     # verdict_s, and no training cell
     assert "verdicts.pod" in [m["name"] for m in
@@ -244,7 +244,7 @@ def test_unknown_cell_and_missing_file_are_errors(tmp_path):
     with pytest.raises(H.BenchError):
         H.resolve_cell("no-such-cell")
     with pytest.raises(H.BenchError):
-        H.reader_for("no_such_metric")
+        H.reader_for(H.resolve_cell("pod16-slowchip"), "no_such_metric")
     with pytest.raises(H.BenchError):
         H.resolve_cell("pod16-slowchip").check("no_such_number", 0.0)
 

@@ -95,11 +95,11 @@ def test_the_step_probe_compiles_one_program_for_every_seed():
                  num_key_value_heads=1, head_dim=16, vocab_size=256)
     frozen = lm._Frozen(small)
     params = lm.make_params_from_seed(small, 1)
-    texts = [drv._change_norms.lower(frozen, params,
+    texts = [drv._change_norms.lower(lm, frozen, params,
                                      jax.random.PRNGKey(seed)).as_text()
              for seed in (5, 2 ** 31 + 77)]
     assert texts[0] == texts[1]
     # the seed's own weights have not changed, to float32 rounding
-    norms = drv._change_norms(frozen, params, jax.random.PRNGKey(1))
+    norms = drv._change_norms(lm, frozen, params, jax.random.PRNGKey(1))
     assert float(jax.numpy.max(norms)) < 1e-5 * float(
         jax.numpy.min(lm.leaf_norms(params)))

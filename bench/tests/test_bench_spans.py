@@ -65,7 +65,7 @@ def run_for(t0, t1):
 
 
 def read(metric, run):
-    return H.reader_for(metric, BENCH).read(run)
+    return H.load_module(BENCH / "layers" / f"{metric}.py").read(run)
 
 
 def test_detector_readers_per_verdict(clock):

@@ -46,6 +46,25 @@ def test_summary_of_a_synthetic_trace():
         {"detect": 5.0, "window": 1.0 + 0.5})
 
 
+def test_a_trace_that_stops_part_way_counts_only_what_it_covers():
+    """The profiler dropped every device event after 4 s of a 10 s window:
+    the time past the last kept operation is not counted as idle."""
+    ops = [[("scan", 0.5, 1.5), ("scan", 2.5, 3.5), ("slice", 3.5, 4.0)]]
+    spans = [("record", 0.0, 1.5), ("detect", 1.5, 2.5),
+             ("record", 2.5, 4.0), ("detect", 4.0, 6.0),
+             ("record", 6.0, 10.0)]
+    s = D.summarize(ops, spans, (0.0, 10.0))
+    assert s["window_s"] == pytest.approx(4.0)
+    assert s["busy_s"] == pytest.approx(2.5)
+    assert s["idle_share"] == pytest.approx(1 - 2.5 / 4.0)
+    assert dict(s["idle_gaps"]) == pytest.approx(
+        {"record": 0.5, "detect": 1.0})
+    # no operation in the window: the whole window, all idle
+    s = D.summarize([[("scan", 11.0, 12.0)]], [], (0.0, 10.0))
+    assert s["window_s"] == pytest.approx(10.0)
+    assert s["idle_share"] == pytest.approx(1.0)
+
+
 def test_busy_is_averaged_over_devices():
     ops = [[("a", 0.0, 4.0)], [("a", 0.0, 2.0)]]
     s = D.summarize(ops, [], (0.0, 4.0))
