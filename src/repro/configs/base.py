@@ -28,12 +28,27 @@ class ArchConfig:
     pos: str = "rope"                 # 'rope' | 'mrope' | 'learned' | 'none'
     window: int | None = None         # sliding-window attention size
     rope_theta: float = 1e4
+    rms_eps: float = 1e-6             # RMSNorm epsilon
+
+    # multi-head latent attention (DeepSeek-V2/V3); kv_lora_rank 0 → off.
+    # head_dim is then the query/key head size, qk_nope_dim + qk_rope_dim
+    kv_lora_rank: int = 0
+    qk_rope_dim: int = 0              # rotary part of each query/key head
+    v_head_dim: int = 0               # 0 → head_dim
 
     # MoE
-    n_experts: int = 0
+    n_experts: int = 0                # the router's width: all experts
     top_k: int = 0
     moe_period: int = 1               # MoE every k-th layer
-    capacity_factor: float = 1.25
+    capacity_factor: float = 1.25     # 0 → dropless (grouped matmuls)
+    d_expert: int = 0                 # routed expert width; 0 → d_ff
+    n_shared_experts: int = 0         # always-on experts of width d_expert
+    experts_held: int = 0             # experts this chip computes; 0 → all
+    expert_offset: int = 0            # the first expert held
+    router_score: str = "softmax"     # 'softmax' | 'sigmoid'
+    router_scale: float = 1.0         # multiplies the normalised weights
+    aux_weight: float = 0.01          # load-balancing loss weight
+    first_dense: int = 0              # leading dense layers before periods
 
     # SSM / hybrid
     attn_period: int = 0              # 0 → all attention; k → 1 attn per k
@@ -58,6 +73,16 @@ class ArchConfig:
         if self.head_dim == 0:
             object.__setattr__(self, "head_dim",
                                self.d_model // max(self.n_heads, 1))
+        if self.v_head_dim == 0:
+            object.__setattr__(self, "v_head_dim", self.head_dim)
+        if self.d_expert == 0:
+            object.__setattr__(self, "d_expert", self.d_ff)
+        if self.experts_held == 0:
+            object.__setattr__(self, "experts_held", self.n_experts)
+
+    @property
+    def mla(self) -> bool:
+        return self.kv_lora_rank > 0
 
     @property
     def d_inner(self) -> int:
@@ -76,8 +101,9 @@ class ArchConfig:
                 else "mamba")
 
     def is_moe_layer(self, li: int) -> bool:
-        return self.n_experts > 0 and li % self.moe_period == \
-            (self.moe_period - 1)
+        li -= self.first_dense
+        return self.n_experts > 0 and li >= 0 and \
+            li % self.moe_period == (self.moe_period - 1)
 
     @property
     def n_attn_layers(self) -> int:
@@ -98,7 +124,9 @@ class ArchConfig:
             total += self.n_frames * d   # learned positions (stub frontend)
         for li in range(self.n_layers):
             total += 2 * d  # norms
-            if self.layer_kind(li) == "attn":
+            if self.layer_kind(li) == "attn" and self.mla:
+                total += self._mla_params()
+            elif self.layer_kind(li) == "attn":
                 hq = self.n_heads * self.head_dim
                 hk = self.n_kv_heads * self.head_dim
                 total += d * (hq + 2 * hk) + hq * d
@@ -112,7 +140,10 @@ class ArchConfig:
                 total += di * d + 2 * self.ssm_heads
             if self.is_moe_layer(li):
                 total += d * self.n_experts  # router
-                total += self.n_experts * 3 * d * ff
+                if self.router_score == "sigmoid":
+                    total += self.n_experts  # selection bias
+                total += (self.experts_held + self.n_shared_experts) \
+                    * 3 * d * self.d_expert
             else:
                 n_mats = 3 if self.mlp == "swiglu" else 2
                 total += n_mats * d * ff
@@ -123,16 +154,24 @@ class ArchConfig:
                 total += 2 * d + d * (hq + 2 * hk) + hq * d + 2 * d * ff
         return total
 
+    def _mla_params(self) -> int:
+        """Latent attention: direct queries, the joint key/value down
+        projection with its shared rotary key, the latent's norm, the
+        up projection to per-head keys and values, the output."""
+        d, h, r = self.d_model, self.n_heads, self.kv_lora_rank
+        nope = self.head_dim - self.qk_rope_dim
+        return (d * h * self.head_dim + d * (r + self.qk_rope_dim) + r
+                + r * h * (nope + self.v_head_dim) + h * self.v_head_dim * d)
+
     def active_param_count(self) -> int:
-        """Parameters touched per token (MoE: top-k experts only)."""
+        """Parameters touched per token (MoE: top-k experts only; of a
+        held share, its expected ``top_k * held / n_experts``)."""
         if self.n_experts == 0:
             return self.param_count()
-        d, ff = self.d_model, self.d_ff
-        inactive = 0
-        for li in range(self.n_layers):
-            if self.is_moe_layer(li):
-                inactive += (self.n_experts - self.top_k) * 3 * d * ff
-        return self.param_count() - inactive
+        per_expert = 3 * self.d_model * self.d_expert
+        idle = self.experts_held * (1 - self.top_k / self.n_experts)
+        n_moe = sum(map(self.is_moe_layer, range(self.n_layers)))
+        return round(self.param_count() - n_moe * idle * per_expert)
 
 
 _REGISTRY: dict[str, "tuple"] = {}
@@ -159,5 +198,5 @@ def _ensure_loaded():
     if _REGISTRY:
         return
     from . import (dbrx_132b, h2o_danube3_4b, jamba_1_5_large,   # noqa: F401
-                   mamba2_1_3b, mixtral_8x7b, qwen2_vl_2b, smollm_135m,
-                   stablelm_1_6b, whisper_large_v3, yi_34b)
+                   mamba2_1_3b, mixtral_8x7b, moonlight_16b_a3b, qwen2_vl_2b,
+                   smollm_135m, stablelm_1_6b, whisper_large_v3, yi_34b)

@@ -311,7 +311,12 @@ def build_lm_graph(cfg, seq: int = 512, batch: int = 8,
     ``cfg`` is a ``repro.configs.base.ArchConfig``.  Per layer we emit the
     block's operators (attention / MoE / SSM) with edges carrying activation
     volumes, so SLOTH sees the same dataflow the accelerator would run.
+    Latent attention, whose q/k and v widths differ from ``head_dim``'s, is
+    not modelled: such a config is refused.
     """
+    if cfg.mla:
+        raise ValueError(f"build_lm_graph counts attention by head_dim; "
+                         f"{cfg.name} uses latent attention")
     g = CompGraph(f"lm:{cfg.name}")
     d = cfg.d_model
     tok_bytes = seq * batch * d * _BYTES

@@ -75,18 +75,22 @@ def skip_reason(cfg: ArchConfig, shape: str) -> str | None:
 # ---------------------------------------------------------------------------
 
 def loss_fn(cfg, params, tokens, enc_frames=None, remat=True):
-    logits, aux = T.forward_train(cfg, params, tokens,
-                                  enc_frames=enc_frames, remat=remat)
+    """(loss with the weighted aux loss, (cross-entropy, expert load))."""
+    logits, aux, load = T.forward_train(cfg, params, tokens,
+                                        enc_frames=enc_frames, remat=remat)
     logits = logits[:, :-1].astype(jnp.float32)
     targets = tokens[:, 1:]
     logz = jax.nn.logsumexp(logits, axis=-1)
     gold = jnp.take_along_axis(logits, targets[..., None],
                                axis=-1)[..., 0]
     ce = (logz - gold).mean()
-    return ce + 0.01 * aux, ce
+    return ce + cfg.aux_weight * aux, (ce, load)
 
 
 def make_train_step(cfg, plan: CellPlan, opt_cfg=None):
+    """The step: (params, opt_state, tokens[, enc_frames]) → (params,
+    opt_state, loss, grad_norm), and for MoE configs the token-expert
+    pairs each held expert computed per MoE layer [MoE layers, held]."""
     opt_cfg = opt_cfg or adamw.AdamWConfig(state_dtype=plan.opt_state_dtype)
 
     def train_step(params, opt_state, tokens, enc_frames=None):
@@ -102,32 +106,36 @@ def make_train_step(cfg, plan: CellPlan, opt_cfg=None):
                 frames = enc_frames.reshape(a, b, *enc_frames.shape[1:])
 
             def acc(carry, xs):
-                g_sum, l_sum = carry
+                g_sum, l_sum, ld_sum = carry
                 tb = xs[0]
                 fb = xs[1] if enc_frames is not None else None
-                (l, _), g = jax.value_and_grad(micro_loss, has_aux=True)(
-                    params, tb, fb)
-                return (jax.tree.map(jnp.add, g_sum, g), l_sum + l), None
+                (l, (_, ld)), g = jax.value_and_grad(
+                    micro_loss, has_aux=True)(params, tb, fb)
+                return (jax.tree.map(jnp.add, g_sum, g), l_sum + l,
+                        jax.tree.map(jnp.add, ld_sum, ld)), None
 
             g0 = jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32),
                               params)
-            xs = (toks, frames) if enc_frames is not None else (toks,
-                                                                None)
+            ld0 = jax.tree.map(
+                lambda s: jnp.zeros(s.shape, s.dtype),
+                jax.eval_shape(micro_loss, params, toks[0],
+                               None if frames is None else frames[0])[1][1])
             if enc_frames is None:
-                (grads, loss), _ = jax.lax.scan(
-                    lambda c, t: acc(c, (t, None)), (g0, 0.0), toks)
+                (grads, loss, load), _ = jax.lax.scan(
+                    lambda c, t: acc(c, (t, None)), (g0, 0.0, ld0), toks)
             else:
-                (grads, loss), _ = jax.lax.scan(acc, (g0, 0.0),
-                                                (toks, frames))
+                (grads, loss, load), _ = jax.lax.scan(
+                    acc, (g0, 0.0, ld0), (toks, frames))
             grads = jax.tree.map(lambda g: g / a, grads)
             loss = loss / a
         else:
-            (loss, _), grads = jax.value_and_grad(
+            (loss, (_, load)), grads = jax.value_and_grad(
                 micro_loss, has_aux=True)(params, tokens, enc_frames)
 
         new_params, new_opt, stats = adamw.apply(params, grads, opt_state,
                                                  opt_cfg)
-        return new_params, new_opt, loss, stats["grad_norm"]
+        out = (new_params, new_opt, loss, stats["grad_norm"])
+        return out if load is None else out + (load,)
 
     return train_step
 
@@ -230,6 +238,8 @@ def build_cell(cfg: ArchConfig, shape: str, mesh, plan: CellPlan | None
             in_sh += (ns(frame_spec),)
         out_sh = (ns(pspec), ns(opt_spec),
                   NamedSharding(mesh, P()), NamedSharding(mesh, P()))
+        if cfg.n_experts:
+            out_sh += (NamedSharding(mesh, P()),)
         return step, args, in_sh, out_sh
 
     if sh["kind"] == "prefill":

@@ -20,7 +20,13 @@ Features exercised end-to-end (CPU-scale here, pod-scale by mesh swap):
     ``train.dispatch`` (the jitted step's call), ``train.sync`` (the wait
     for the loss), and ``train.telemetry``, ``train.log``, ``train.ckpt``
     where the step does that work.  The telemetry signal is the step's
-    ``dispatch`` start to its ``sync`` end on the one clock.
+    ``dispatch`` start to its ``sync`` end on the one clock.  For MoE
+    configs ``train.sync`` also counts ``expert_tokens``, the token-expert
+    pairs the held experts computed in the step, and ``expert_load_max``,
+    the most that one held expert computed in one layer.
+  * ``--remat`` recomputes each layer's activations in the backward pass
+    (a model whose activations do not fit beside its weights and AdamW
+    state).
 
 Usage (CPU example):
   PYTHONPATH=src python -m repro.launch.train --arch smollm-135m --smoke \
@@ -80,6 +86,9 @@ def main(argv=None):
                     help="exit nonzero unless telemetry flagged a slow "
                     "window (CI smoke assertion)")
     ap.add_argument("--log-every", type=int, default=10)
+    ap.add_argument("--remat", action="store_true",
+                    help="recompute each layer's activations in the "
+                    "backward pass")
     args = ap.parse_args(argv)
     enable_compile_cache()
 
@@ -105,7 +114,7 @@ def main(argv=None):
             start_step = latest
             print(f"[resume] step {latest}")
 
-    plan = steps_mod.CellPlan(grad_accum=1, remat=False,
+    plan = steps_mod.CellPlan(grad_accum=1, remat=args.remat,
                               param_dtype=jnp.float32)
     train_step = jax.jit(steps_mod.make_train_step(cfg, plan, opt_cfg),
                          donate_argnums=(0, 1))
@@ -131,13 +140,17 @@ def main(argv=None):
                 tokens = jnp.asarray(next(pipe))
             with spans.span("train.dispatch") as dispatch:
                 if cfg.enc_dec:
-                    params, opt_state, loss, gnorm = train_step(
+                    params, opt_state, loss, gnorm, *load = train_step(
                         params, opt_state, tokens, enc_frames)
                 else:
-                    params, opt_state, loss, gnorm = train_step(
+                    params, opt_state, loss, gnorm, *load = train_step(
                         params, opt_state, tokens)
             with spans.span("train.sync") as sync:
                 loss = float(loss)
+                if load:
+                    load = np.asarray(load[0])
+                    spans.count("expert_tokens", int(load.sum()))
+                    spans.count("expert_load_max", int(load.max()))
             losses.append(loss)
             # the telemetry signal: the step's call to its loss on the host
             dt = sync.t1 - dispatch.t0

@@ -8,9 +8,11 @@ Attention notes:
 * GQA is expressed by grouping query heads over KV heads in the einsums, so
   SPMD sharding of the flattened head dim stays clean.
 
-MoE uses sort-based token dispatch (argsort by expert id + capacity
-truncation): FLOPs stay proportional to top-k, memory O(E·C·d), all ops
-shard under pjit.
+MoE uses sort-based token dispatch (argsort by expert id).  With a
+capacity factor the sorted tokens fill E·C slots and overflow is dropped
+(FLOPs proportional to top-k, memory O(E·C·d)).  Without one the layer is
+dropless: the matmuls are grouped over the experts this chip holds
+(``lax.ragged_dot`` over the sorted tokens), however skewed the routing.
 """
 
 from __future__ import annotations
@@ -44,7 +46,7 @@ def layer_norm(x, scale, bias, eps=1e-5):
 
 def apply_norm(cfg, x, p):
     if cfg.norm == "rms":
-        return rms_norm(x, p["scale"])
+        return rms_norm(x, p["scale"], cfg.rms_eps)
     return layer_norm(x, p["scale"], p["bias"])
 
 
@@ -136,11 +138,12 @@ def _direct_attention(q, k, v, q_pos, k_pos, causal, window):
     scores = jnp.where(mask[None, None, None], scores, NEG_INF)
     probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
     out = jnp.einsum("bhgst,bthd->bshgd", probs, v)
-    return out.reshape(b, s, hq, d)
+    return out.reshape(b, s, hq, v.shape[-1])
 
 
 def _chunked_attention(q, k, v, q_pos, k_pos, causal, window, kv_chunk):
-    """Online-softmax scan over KV chunks: O(S·chunk) live memory."""
+    """Online-softmax scan over KV chunks: O(S·chunk) live memory, in the
+    backward pass too."""
     b, s, hq, d = q.shape
     t, hk = k.shape[1], k.shape[2]
     g = hq // hk
@@ -151,8 +154,9 @@ def _chunked_attention(q, k, v, q_pos, k_pos, causal, window, kv_chunk):
         k = jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0)))
         v = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))
         k_pos = jnp.pad(k_pos, (0, pad), constant_values=2**30)
+    dv = v.shape[-1]
     kc = k.reshape(b, n_chunks, kv_chunk, hk, d).transpose(1, 0, 2, 3, 4)
-    vc = v.reshape(b, n_chunks, kv_chunk, hk, d).transpose(1, 0, 2, 3, 4)
+    vc = v.reshape(b, n_chunks, kv_chunk, hk, dv).transpose(1, 0, 2, 3, 4)
     pc = k_pos.reshape(n_chunks, kv_chunk)
 
     def step(carry, xs):
@@ -177,10 +181,12 @@ def _chunked_attention(q, k, v, q_pos, k_pos, causal, window, kv_chunk):
 
     m0 = jnp.full((b, hk, g, s), NEG_INF, jnp.float32)
     l0 = jnp.zeros((b, hk, g, s), jnp.float32)
-    a0 = jnp.zeros((b, hk, g, s, d), jnp.float32)
-    (m, l, acc), _ = jax.lax.scan(step, (m0, l0, a0), (kc, vc, pc))
+    a0 = jnp.zeros((b, hk, g, s, dv), jnp.float32)
+    # each chunk's scores are recomputed in the backward pass, not kept
+    (m, l, acc), _ = jax.lax.scan(jax.checkpoint(step), (m0, l0, a0),
+                                  (kc, vc, pc))
     out = (acc / jnp.maximum(l, 1e-30)[..., None]).astype(q.dtype)
-    return out.transpose(0, 3, 1, 2, 4).reshape(b, s, hq, d)
+    return out.transpose(0, 3, 1, 2, 4).reshape(b, s, hq, dv)
 
 
 def _banded_attention(q, k, v, q_pos, k_pos, window, q_chunk):
@@ -218,13 +224,15 @@ def _banded_attention(q, k, v, q_pos, k_pos, window, q_chunk):
 
     out = jax.lax.map(lambda xs: one_chunk(xs[0], xs[1], xs[2]),
                       (qg, qp, jnp.arange(n_q)))
-    out = out.transpose(1, 0, 2, 3, 4, 5).reshape(b, n_q * q_chunk, hq, d)
+    out = out.transpose(1, 0, 2, 3, 4, 5).reshape(b, n_q * q_chunk, hq,
+                                                  v.shape[-1])
     return out[:, :s]
 
 
 def attention(q, k, v, *, q_pos, k_pos, causal=True, window=None,
               kv_chunk=1024, q_chunk=512):
-    """q [B,S,Hq,D], k/v [B,T,Hkv,D], positions int32 [S]/[T] (absolute)."""
+    """q [B,S,Hq,D], k [B,T,Hkv,D], v [B,T,Hkv,Dv], positions int32
+    [S]/[T] (absolute) → [B,S,Hq,Dv]."""
     s, t = q.shape[1], k.shape[1]
     if s == 1 or (s * t) <= (2048 * 2048):
         return _direct_attention(q, k, v, q_pos, k_pos, causal, window)
@@ -267,60 +275,123 @@ def mlp(cfg, p, x):
 # ---------------------------------------------------------------------------
 
 def init_moe(cfg, rng, dtype):
-    d, ff, e = cfg.d_model, cfg.d_ff, cfg.n_experts
+    """Router over all ``n_experts``; weights of the ``experts_held`` this
+    chip computes; the shared experts as one MLP of their summed width."""
+    d, ff, e, held = cfg.d_model, cfg.d_expert, cfg.n_experts, \
+        cfg.experts_held
     k0, k1, k2, k3 = jax.random.split(rng, 4)
     std = d ** -0.5
-    return {
+    p = {
         "router": (jax.random.normal(k0, (d, e)) * std).astype(jnp.float32),
-        "w_gate": (jax.random.normal(k1, (e, d, ff)) * std).astype(dtype),
-        "w_up": (jax.random.normal(k2, (e, d, ff)) * std).astype(dtype),
-        "w_down": (jax.random.normal(k3, (e, ff, d)) * (ff ** -0.5)
+        "w_gate": (jax.random.normal(k1, (held, d, ff)) * std).astype(dtype),
+        "w_up": (jax.random.normal(k2, (held, d, ff)) * std).astype(dtype),
+        "w_down": (jax.random.normal(k3, (held, ff, d)) * (ff ** -0.5)
                    ).astype(dtype),
     }
+    if cfg.router_score == "sigmoid":
+        # noaux_tc's selection bias (DeepSeek-V3's e_score_correction_bias)
+        p["router_bias"] = jnp.zeros((e,), jnp.float32)
+    if cfg.n_shared_experts:
+        p["shared"] = init_mlp(cfg, jax.random.fold_in(rng, 4), d,
+                               cfg.n_shared_experts * ff, dtype)
+    return p
+
+
+def route(cfg, p, xf):
+    """Each token's ``top_k`` experts [T,k] and their weights [T,k], and
+    the load-balancing loss.  Softmax routers renormalise the top-k
+    probabilities; sigmoid routers (DeepSeek-V3) pick by score plus the
+    selection bias, weigh by the score, renormalise and scale."""
+    k = cfg.top_k
+    sigmoid = cfg.router_score == "sigmoid"
+    logits = jnp.dot(xf.astype(jnp.float32), p["router"],
+                     precision=jax.lax.Precision.HIGHEST if sigmoid
+                     else None)                              # [T,E]
+    if sigmoid:
+        scores = jax.nn.sigmoid(logits)
+        _, idx = jax.lax.top_k(scores + p["router_bias"], k)
+        vals = jnp.take_along_axis(scores, idx, axis=-1)
+    else:
+        scores = jax.nn.softmax(logits, axis=-1)
+        vals, idx = jax.lax.top_k(scores, k)
+    vals = vals / jnp.maximum(vals.sum(-1, keepdims=True), 1e-9) \
+        * cfg.router_scale
+    aux = jnp.zeros((), jnp.float32)
+    if cfg.aux_weight:   # Switch-style load balance
+        density = jnp.mean(jax.nn.one_hot(idx[:, 0], cfg.n_experts), axis=0)
+        aux = cfg.n_experts * jnp.sum(density * jnp.mean(scores, axis=0))
+    return idx, vals, aux
 
 
 def moe(cfg, p, x):
-    """x [B,S,d] → [B,S,d], plus the load-balancing aux loss."""
+    """x [B,S,d] → ([B,S,d], aux loss, load [held]).
+
+    Routes over all experts and computes the part of the result that the
+    held experts ``[expert_offset, expert_offset + experts_held)`` give,
+    plus the shared experts.  ``load`` counts the token-expert pairs each
+    held expert computed."""
     b, s, d = x.shape
-    e, k = cfg.n_experts, cfg.top_k
+    held, k = cfg.experts_held, cfg.top_k
     tokens = b * s
     xf = x.reshape(tokens, d)
 
-    logits = (xf.astype(jnp.float32) @ p["router"])          # [T,E]
-    probs = jax.nn.softmax(logits, axis=-1)
-    gate_vals, gate_idx = jax.lax.top_k(probs, k)            # [T,k]
-    gate_vals = gate_vals / jnp.maximum(
-        gate_vals.sum(-1, keepdims=True), 1e-9)
+    with jax.named_scope("moe.route"):
+        idx, vals, aux = route(cfg, p, xf)
+        local = idx.reshape(-1) - cfg.expert_offset           # [T·k]
+        key = jnp.where((local >= 0) & (local < held), local, held)
+        t_flat = jnp.repeat(jnp.arange(tokens), k)
+        order = jnp.argsort(key)          # held experts' pairs first
+        se, st, sw = key[order], t_flat[order], vals.reshape(-1)[order]
+        counts = jnp.bincount(key, length=held + 1)[:held]
 
-    # load-balance aux loss (Switch-style)
-    density = jnp.mean(jax.nn.one_hot(gate_idx[:, 0], e), axis=0)
-    mean_prob = jnp.mean(probs, axis=0)
-    aux = e * jnp.sum(density * mean_prob)
+    with jax.named_scope("moe.experts"):
+        if cfg.capacity_factor:
+            ye, kept, load = _capacity_experts(cfg, p, xf, se, st, counts)
+        else:
+            ye, kept, load = _grouped_experts(p, xf, se, st, counts)
+        # rows outside the held experts' groups are zeroed before they are
+        # weighed, so that nothing in them reaches a gradient
+        contrib = jnp.where(kept[:, None], ye, 0) \
+            * sw[:, None].astype(x.dtype)
+        y = jnp.zeros((tokens, d), x.dtype).at[st].add(contrib)
+    y = y.reshape(b, s, d)
+    if "shared" in p:
+        with jax.named_scope("moe.shared"):
+            y = y + mlp(cfg, p["shared"], x)
+    return y, aux, load
 
-    cap = int(np.ceil(tokens * k / e * cfg.capacity_factor))
+
+def _capacity_experts(cfg, p, xf, se, st, counts):
+    """Every held expert's tokens in ``cap`` slots, overflow dropped."""
+    held, tokens = cfg.experts_held, xf.shape[0]
+    cap = int(np.ceil(tokens * cfg.top_k / cfg.n_experts
+                      * cfg.capacity_factor))
     cap = max(8, -(-cap // 8) * 8)   # pad to multiple of 8
-
-    e_flat = gate_idx.reshape(-1)                            # [T·k]
-    t_flat = jnp.repeat(jnp.arange(tokens), k)
-    w_flat = gate_vals.reshape(-1)
-    order = jnp.argsort(e_flat)
-    se, st, sw = e_flat[order], t_flat[order], w_flat[order]
-    counts = jnp.bincount(e_flat, length=e)
     starts = jnp.concatenate([jnp.zeros(1, counts.dtype),
                               jnp.cumsum(counts)[:-1]])
-    pos_in_e = jnp.arange(tokens * k) - starts[se]
-    keep = pos_in_e < cap
-    slot = se * cap + jnp.where(keep, pos_in_e, 0)
+    pos_in_e = jnp.arange(se.shape[0]) - starts[jnp.minimum(se, held - 1)]
+    keep = (pos_in_e < cap) & (se < held)
+    slot = jnp.where(keep, se * cap + pos_in_e, 0)
 
-    xe = jnp.zeros((e * cap, d), x.dtype)
+    xe = jnp.zeros((held * cap, xf.shape[1]), xf.dtype)
     xe = xe.at[slot].add(jnp.where(keep[:, None], xf[st], 0))
-    xe = xe.reshape(e, cap, d)
-
+    xe = xe.reshape(held, cap, -1)
     g = jax.nn.silu(jnp.einsum("ecd,edf->ecf", xe, p["w_gate"]))
     u = jnp.einsum("ecd,edf->ecf", xe, p["w_up"])
     ye = jnp.einsum("ecf,efd->ecd", g * u, p["w_down"])
-    ye = ye.reshape(e * cap, d)
+    return ye.reshape(held * cap, -1)[slot], keep, jnp.minimum(counts, cap)
 
-    contrib = ye[slot] * (sw * keep)[:, None].astype(x.dtype)
-    y = jnp.zeros((tokens, d), x.dtype).at[st].add(contrib)
-    return y.reshape(b, s, d), aux
+
+def _grouped_experts(p, xf, se, st, counts):
+    """Dropless: one matmul per weight, grouped over the held experts'
+    sorted tokens.  Rows of other experts belong to no group, and
+    ``ragged_dot`` leaves them undefined on the TPU, in its gradient too:
+    they are zeroed on the way in (so their gradient never reaches a
+    token) and on the way out."""
+    kept = se < counts.shape[0]
+    gs = counts.astype(jnp.int32)
+    xs = jnp.where(kept[:, None], xf[st], 0)
+    g = jax.nn.silu(jax.lax.ragged_dot(xs, p["w_gate"], gs))
+    u = jax.lax.ragged_dot(xs, p["w_up"], gs)
+    ye = jax.lax.ragged_dot(g * u, p["w_down"], gs)
+    return ye, kept, counts

@@ -83,14 +83,14 @@ def param_specs(params_shape, mesh, *, fsdp_axis: str | None = None,
                 embed_mode: str = "dmodel"):
     """Pytree of PartitionSpec matching ``jax.eval_shape(init_model, ...)``.
 
-    Leaves under 'periods'/'encoder' carry a stacked leading axis which is
-    never sharded (scan slices it)."""
+    Leaves under 'periods'/'lead'/'encoder' carry a stacked leading axis
+    which is never sharded (scan slices it)."""
     ep = "model" if expert_parallel else None
 
     def assign(path, leaf):
         keys = tuple(p.key for p in path if hasattr(p, "key"))
         shape = leaf.shape
-        stacked = any(k in ("periods", "encoder") for k in keys)
+        stacked = any(k in ("periods", "lead", "encoder") for k in keys)
         if stacked:
             spec = _rule(keys, shape[1:], fsdp=fsdp_axis, ep=ep,
                          embed_mode=embed_mode)

@@ -5,7 +5,9 @@ repeats with period = lcm(attn_period, moe_period) (jamba: 8 = one attention
 + seven mamba layers, MoE on every other layer; dense/MoE archs: 1).  The
 forward pass is a ``lax.scan`` over periods whose body applies the period's
 slots in order — this keeps the lowered HLO small (one period body) and
-makes per-layer FSDP gathering natural.
+makes per-layer FSDP gathering natural.  Leading dense layers
+(``first_dense``, DeepSeek-V3's ``first_k_dense_replace``) are a ``lead``
+stack scanned the same way before the periods.
 
 Three entry points:
   * ``forward_train``  — full-sequence causal forward (no cache) → logits
@@ -19,6 +21,7 @@ assignment.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import math
 from functools import partial
@@ -45,20 +48,47 @@ def period_len(cfg) -> int:
 def period_slots(cfg) -> list[tuple[str, bool]]:
     """[(kind, is_moe)] for one period."""
     return [(cfg.layer_kind(i), cfg.is_moe_layer(i))
-            for i in range(period_len(cfg))]
+            for i in range(cfg.first_dense, cfg.first_dense + period_len(cfg))]
+
+
+#: the slots of the ``lead`` stack: one dense attention layer each
+LEAD_SLOTS = [("attn", False)]
 
 
 def n_periods(cfg) -> int:
-    pl = period_len(cfg)
-    assert cfg.n_layers % pl == 0, (cfg.n_layers, pl)
-    return cfg.n_layers // pl
+    pl, n = period_len(cfg), cfg.n_layers - cfg.first_dense
+    if n % pl:
+        raise ValueError(f"{cfg.name}: {n} layers after the leading dense "
+                         f"ones are not whole periods of {pl}")
+    return n // pl
 
 
 # ---------------------------------------------------------------------------
 # init
 # ---------------------------------------------------------------------------
 
+def _init_mla(cfg, rng, dtype):
+    """Latent attention in its training form: direct queries, the joint
+    down projection to the latent and the shared rotary key, the latent's
+    norm, its up projection to per-head keys and values, the output."""
+    d, h, r = cfg.d_model, cfg.n_heads, cfg.kv_lora_rank
+    nope = cfg.head_dim - cfg.qk_rope_dim
+    hv = h * cfg.v_head_dim
+    k = jax.random.split(rng, 4)
+    normal = lambda key, shape, fan_in: (  # noqa: E731
+        jax.random.normal(key, shape) * fan_in ** -0.5).astype(dtype)
+    return {
+        "wq": normal(k[0], (d, h * cfg.head_dim), d),
+        "wkv_a": normal(k[1], (d, r + cfg.qk_rope_dim), d),
+        "kv_norm": L.init_norm(cfg, r),
+        "wkv_b": normal(k[2], (r, h * (nope + cfg.v_head_dim)), r),
+        "wo": normal(k[3], (hv, d), hv),
+    }
+
+
 def _init_attn(cfg, rng, dtype, cross: bool = False):
+    if cfg.mla:
+        return _init_mla(cfg, rng, dtype)
     d = cfg.d_model
     hq = cfg.n_heads * cfg.head_dim
     hk = cfg.n_kv_heads * cfg.head_dim
@@ -111,6 +141,10 @@ def init_model(cfg, rng, dtype=jnp.bfloat16):
 
     period_keys = jax.random.split(ks[2], np_)
     params["periods"] = jax.vmap(init_period)(period_keys)
+    if cfg.first_dense:
+        params["lead"] = jax.vmap(lambda k: {"slot0": _init_slot(
+            cfg, k, *LEAD_SLOTS[0], dtype)})(
+                jax.random.split(ks[4], cfg.first_dense))
 
     if cfg.enc_dec:
         ek = jax.random.split(ks[3], cfg.n_enc_layers + 1)
@@ -140,19 +174,41 @@ def _sinusoid(positions, d):
     return jnp.concatenate([jnp.sin(ang), jnp.cos(ang)], axis=-1)
 
 
+def _mla_qkv(cfg, p, x, positions):
+    """Per-head q, k (head_dim = nope + rope) and v (v_head_dim) of latent
+    attention: k and v come up from the normed latent; the rotary part of
+    k is one head that all heads share."""
+    b, s, _ = x.shape
+    h, r, rope = cfg.n_heads, cfg.kv_lora_rank, cfg.qk_rope_dim
+    nope = cfg.head_dim - rope
+    q = (x @ p["wq"]).reshape(b, s, h, cfg.head_dim)
+    ckv = x @ p["wkv_a"]
+    c = L.rms_norm(ckv[..., :r], p["kv_norm"]["scale"], cfg.rms_eps)
+    kv = (c @ p["wkv_b"]).reshape(b, s, h, nope + cfg.v_head_dim)
+    q_pe = L.apply_rope(q[..., nope:], positions, cfg.rope_theta)
+    k_pe = L.apply_rope(ckv[:, :, None, r:], positions, cfg.rope_theta)
+    q = jnp.concatenate([q[..., :nope], q_pe], axis=-1)
+    k = jnp.concatenate([kv[..., :nope],
+                         jnp.broadcast_to(k_pe, (b, s, h, rope))], axis=-1)
+    return q, k, kv[..., nope:]
+
+
 def _attn_sublayer(cfg, p, x, q_pos, k_pos, *, kv=None, cache=None,
                    causal=True):
     """Self/cross attention.  kv: source for K/V (cross-attn memory)."""
     b, s, d = x.shape
-    src = kv if kv is not None else x
-    q = (x @ p["wq"]).reshape(b, s, cfg.n_heads, cfg.head_dim)
-    k = (src @ p["wk"]).reshape(b, src.shape[1], cfg.n_kv_heads,
-                                cfg.head_dim)
-    v = (src @ p["wv"]).reshape(b, src.shape[1], cfg.n_kv_heads,
-                                cfg.head_dim)
-    if kv is None:
-        q, k = L.position_embed(cfg, q, k, jnp.broadcast_to(
-            q_pos[None], (b, s)))
+    positions = jnp.broadcast_to(q_pos[None], (b, s))
+    if cfg.mla:
+        q, k, v = _mla_qkv(cfg, p, x, positions)
+    else:
+        src = kv if kv is not None else x
+        q = (x @ p["wq"]).reshape(b, s, cfg.n_heads, cfg.head_dim)
+        k = (src @ p["wk"]).reshape(b, src.shape[1], cfg.n_kv_heads,
+                                    cfg.head_dim)
+        v = (src @ p["wv"]).reshape(b, src.shape[1], cfg.n_kv_heads,
+                                    cfg.head_dim)
+        if kv is None:
+            q, k = L.position_embed(cfg, q, k, positions)
     if cache is not None:
         kc, vc, pc = cache.update(k, v, q_pos)
         if s == 1:
@@ -165,7 +221,7 @@ def _attn_sublayer(cfg, p, x, q_pos, k_pos, *, kv=None, cache=None,
     window = cfg.window if kv is None else None
     out = L.attention(q, k, v, q_pos=q_pos, k_pos=k_pos,
                       causal=causal and kv is None, window=window)
-    return out.reshape(b, s, cfg.n_heads * cfg.head_dim) @ p["wo"]
+    return out.reshape(b, s, cfg.n_heads * cfg.v_head_dim) @ p["wo"]
 
 
 @dataclasses.dataclass
@@ -204,20 +260,24 @@ class _KVView:
 def _apply_slot(cfg, p, kind, is_moe, x, q_pos, *, memory=None,
                 slot_cache=None, dtype=None):
     """One layer: mixer + (cross-attn) + MLP/MoE with residuals.
-    Returns (x, aux_loss, new_slot_cache)."""
+    Returns (x, aux_loss, expert_load or None, new_slot_cache)."""
     aux = jnp.zeros((), jnp.float32)
-    new_cache = None
+    load = new_cache = None
     h = L.apply_norm(cfg, x, p["norm1"])
     if kind == "attn":
-        if slot_cache is not None:
-            view = _KVView(slot_cache["k"], slot_cache["v"],
-                           slot_cache["pos_tab"], slot_cache["pos"])
-            out = _attn_sublayer(cfg, p["attn"], h, q_pos, None, cache=view)
-            new_cache = {"k": view.new[0], "v": view.new[1],
-                         "pos_tab": view.new[2],
-                         "pos": slot_cache["pos"] + h.shape[1]}
-        else:
-            out = _attn_sublayer(cfg, p["attn"], h, q_pos, q_pos)
+        scope = (jax.named_scope("mla") if cfg.mla
+                 else contextlib.nullcontext())
+        with scope:
+            if slot_cache is not None:
+                view = _KVView(slot_cache["k"], slot_cache["v"],
+                               slot_cache["pos_tab"], slot_cache["pos"])
+                out = _attn_sublayer(cfg, p["attn"], h, q_pos, None,
+                                     cache=view)
+                new_cache = {"k": view.new[0], "v": view.new[1],
+                             "pos_tab": view.new[2],
+                             "pos": slot_cache["pos"] + h.shape[1]}
+            else:
+                out = _attn_sublayer(cfg, p["attn"], h, q_pos, q_pos)
         x = x + out
         if cfg.enc_dec and memory is not None:
             hx = L.apply_norm(cfg, x, p["xnorm"])
@@ -237,11 +297,11 @@ def _apply_slot(cfg, p, kind, is_moe, x, q_pos, *, memory=None,
     if cfg.d_ff > 0:
         h2 = L.apply_norm(cfg, x, p["norm2"])
         if is_moe:
-            out2, aux = L.moe(cfg, p["moe"], h2)
+            out2, aux, load = L.moe(cfg, p["moe"], h2)
         else:
             out2 = L.mlp(cfg, p["mlp"], h2)
         x = x + out2
-    return x, aux, new_cache
+    return x, aux, load, new_cache
 
 
 # ---------------------------------------------------------------------------
@@ -283,57 +343,93 @@ def _logits(cfg, params, x):
     return x @ params["lm_head"]
 
 
-def _scan_periods(cfg, params, x, q_pos, memory=None, caches=None,
-                  remat=True):
-    slots = period_slots(cfg)
-
+def _scan_stack(cfg, stack, slots, x, q_pos, memory, caches, remat):
+    """Scan a stack of layers (leading axis) whose body applies ``slots``.
+    Returns (x, aux_loss, expert loads [n, moe slots, held] or None,
+    new caches)."""
     def body(carry, xs):
         h, aux = carry
         pp = xs if caches is None else xs[0]
         cc = None if caches is None else xs[1]
         h = policy.constrain_residual(h)
-        new_cc = {}
+        new_cc, loads = {}, []
         for i, (kind, moe) in enumerate(slots):
             sc = None if cc is None else cc[f"slot{i}"]
-            h, a, nc = _apply_slot(cfg, pp[f"slot{i}"], kind, moe, h, q_pos,
-                                   memory=memory, slot_cache=sc)
+            h, a, load, nc = _apply_slot(cfg, pp[f"slot{i}"], kind, moe, h,
+                                         q_pos, memory=memory,
+                                         slot_cache=sc)
             aux = aux + a
+            if load is not None:
+                loads.append(load)
             if nc is not None:
                 if "ssm" in nc:
                     nc["ssm"] = policy.constrain_state(nc["ssm"])
                 new_cc[f"slot{i}"] = nc
         h = policy.constrain_residual(h)
-        return (h, aux), (new_cc if caches is not None else None)
+        return (h, aux), (new_cc if caches is not None else None,
+                          jnp.stack(loads) if loads else None)
 
     fn = jax.checkpoint(body,
                         policy=jax.checkpoint_policies.nothing_saveable) \
         if remat else body
-    xs = params["periods"] if caches is None else (params["periods"], caches)
-    (x, aux), new_caches = jax.lax.scan(fn, (x, jnp.zeros((), jnp.float32)),
-                                        xs)
-    return x, aux, new_caches
+    xs = stack if caches is None else (stack, caches)
+    (x, aux), (new_caches, loads) = jax.lax.scan(
+        fn, (x, jnp.zeros((), jnp.float32)), xs)
+    return x, aux, loads, new_caches
+
+
+def _scan_periods(cfg, params, x, q_pos, memory=None, caches=None,
+                  remat=True):
+    """The ``lead`` stack, then the periods.  Returns (x, aux_loss, expert
+    load [MoE layers, held] or None, new caches)."""
+    new_lead = None
+    if cfg.first_dense:
+        lead_c = None if caches is None else caches["lead"]
+        x, _, _, new_lead = _scan_stack(cfg, params["lead"], LEAD_SLOTS, x,
+                                        q_pos, memory, lead_c, remat)
+        if caches is not None:
+            caches = {k: c for k, c in caches.items() if k != "lead"}
+    x, aux, loads, new_caches = _scan_stack(
+        cfg, params["periods"], period_slots(cfg), x, q_pos, memory, caches,
+        remat)
+    if new_lead is not None:
+        new_caches["lead"] = new_lead
+    if loads is not None:
+        loads = loads.reshape(-1, loads.shape[-1])
+    return x, aux, loads, new_caches
 
 
 def forward_train(cfg, params, tokens, enc_frames=None, remat=True):
-    """tokens [B,S] → (logits [B,S,V], aux_loss)."""
+    """tokens [B,S] → (logits [B,S,V], aux_loss, expert load): the load is
+    the token-expert pairs each held expert computed, [MoE layers, held],
+    or None without experts."""
     s = tokens.shape[1]
     positions = jnp.arange(s)
     x = _embed(cfg, params, tokens, positions)
     memory = None
     if cfg.enc_dec:
         memory = run_encoder(cfg, params, enc_frames)
-    x, aux, _ = _scan_periods(cfg, params, x, positions, memory,
-                              remat=remat)
-    return _logits(cfg, params, x), aux
+    x, aux, load, _ = _scan_periods(cfg, params, x, positions, memory,
+                                    remat=remat)
+    return _logits(cfg, params, x), aux, load
 
 
 # -- cache construction -------------------------------------------------------
 
 def init_cache(cfg, batch, cache_len, dtype=jnp.bfloat16,
                memory_len: int = 0):
-    """Cache pytree with leading period axis (scan xs/ys layout)."""
-    np_ = n_periods(cfg)
-    slots = period_slots(cfg)
+    """Cache pytree with leading period axis (scan xs/ys layout); with
+    leading dense layers, their cache under ``lead``.  Latent attention
+    keeps per-head keys and values (the expanded form)."""
+    per = _stack_cache(cfg, n_periods(cfg), period_slots(cfg), batch,
+                       cache_len, dtype)
+    if cfg.first_dense:
+        per["lead"] = _stack_cache(cfg, cfg.first_dense, LEAD_SLOTS, batch,
+                                   cache_len, dtype)
+    return per
+
+
+def _stack_cache(cfg, np_, slots, batch, cache_len, dtype):
     t_max = cache_len if cfg.window is None else min(cache_len,
                                                      cfg.window)
     per = {}
@@ -343,7 +439,7 @@ def init_cache(cfg, batch, cache_len, dtype=jnp.bfloat16,
                 "k": jnp.zeros((np_, batch, t_max, cfg.n_kv_heads,
                                 cfg.head_dim), dtype),
                 "v": jnp.zeros((np_, batch, t_max, cfg.n_kv_heads,
-                                cfg.head_dim), dtype),
+                                cfg.v_head_dim), dtype),
                 "pos_tab": jnp.full((np_, t_max), -1, jnp.int32),
                 "pos": jnp.zeros((np_,), jnp.int32),
             }
@@ -369,8 +465,8 @@ def prefill(cfg, params, tokens, cache, enc_frames=None, remat=True):
     memory = None
     if cfg.enc_dec:
         memory = run_encoder(cfg, params, enc_frames)
-    x, _, new_caches = _scan_periods(cfg, params, x, positions, memory,
-                                     caches=cache, remat=remat)
+    x, _, _, new_caches = _scan_periods(cfg, params, x, positions, memory,
+                                        caches=cache, remat=remat)
     return _logits(cfg, params, x[:, -1:]), new_caches, memory
 
 
@@ -378,6 +474,6 @@ def decode_step(cfg, params, tokens, cache, pos, memory=None):
     """One decode step.  tokens [B,1]; pos: scalar int32 absolute position."""
     positions = jnp.full((1,), pos, jnp.int32)
     x = _embed(cfg, params, tokens, positions)
-    x, _, new_caches = _scan_periods(cfg, params, x, positions, memory,
-                                     caches=cache, remat=False)
+    x, _, _, new_caches = _scan_periods(cfg, params, x, positions, memory,
+                                        caches=cache, remat=False)
     return _logits(cfg, params, x), new_caches

@@ -25,7 +25,8 @@ def test_skip_rules():
     skipped = {a for a in list_archs()
                if steps.skip_reason(get_config(a), "long_500k")}
     assert skipped == {"qwen2-vl-2b", "whisper-large-v3", "yi-34b",
-                       "smollm-135m", "stablelm-1.6b", "dbrx-132b"}
+                       "smollm-135m", "stablelm-1.6b", "dbrx-132b",
+                       "moonlight-16b-a3b", "moonlight-16b-a3b-ep8"}
     for a in list_archs():
         for sh in ("train_4k", "prefill_32k", "decode_32k"):
             assert steps.skip_reason(get_config(a), sh) is None
